@@ -30,7 +30,7 @@ ARG JAX_EXTRA=""
 COPY --from=builder /dist/ /tmp/wheel/
 RUN pip install --no-cache-dir /tmp/wheel/*.whl ${JAX_EXTRA} \
     && cp /tmp/wheel/_ktnative.so \
-        "$(python -c 'import kube_throttler_tpu.native as n, pathlib; print(pathlib.Path(n.__file__).parent)')/_ktnative.so" \
+        "$(python -c 'import kube_throttler_tpu.native as n; print(n.package_so_path())')" \
     && rm -rf /tmp/wheel
 
 # non-root like the reference deployment expects; the flock lease and the

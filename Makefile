@@ -104,8 +104,8 @@ bench-quick:
 walkthrough:     ## reference README walkthrough end-to-end
 	$(PY) examples/walkthrough.py
 
-smoke:           ## TPU kernel compatibility smoke on real hardware
-	$(PY) tools/tpu_smoke.py
+smoke:           ## the served daemon end to end on one TPU chip (fails without one)
+	$(PY) chip_smoke.py
 
 serve:           ## run the daemon against the sample config
 	$(PY) -m kube_throttler_tpu.cli serve --name kube-throttler \

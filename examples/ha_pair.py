@@ -33,7 +33,7 @@ def launch(role: str, workdir: str, lock: str, port: int, extra):
     os.makedirs(datadir, exist_ok=True)
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO_ROOT + os.pathsep + env.get("PYTHONPATH", "")
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     return subprocess.Popen(
         [
             sys.executable, "-m", "kube_throttler_tpu.cli", "serve",

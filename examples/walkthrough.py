@@ -10,10 +10,6 @@ from dataclasses import replace
 
 sys.path.insert(0, ".")
 
-from kube_throttler_tpu.utils.platform import honor_jax_platforms_env
-
-honor_jax_platforms_env()
-
 from kube_throttler_tpu.api import (
     LabelSelector,
     Namespace,

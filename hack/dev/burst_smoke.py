@@ -26,10 +26,6 @@ import time
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, REPO_ROOT)
 
-from kube_throttler_tpu.utils.platform import honor_jax_platforms_env  # noqa: E402
-
-honor_jax_platforms_env()
-
 from kube_throttler_tpu.api import ResourceAmount, Throttle, ThrottleSpec  # noqa: E402
 from kube_throttler_tpu.api.pod import make_pod  # noqa: E402
 from kube_throttler_tpu.api.serialization import object_to_dict  # noqa: E402

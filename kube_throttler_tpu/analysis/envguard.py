@@ -4,8 +4,8 @@ The ``_GATHER_CHUNK_ELEMS`` bug class (ADVICE round 5): a bare
 ``int(os.environ.get("KT_GATHER_CHUNK_ELEMS", ...))`` at import time
 means one malformed override kills module import — or, on a serving
 path, kills the daemon at the first tick that reads the knob. The
-repo convention (``tpu_watch.py``'s ``KT_TUNNEL_PROBE_PORT`` guard,
-``gchygiene.py``) is ``try: int(...) except ValueError: <default>``.
+repo convention (``gchygiene.py``) is
+``try: int(...) except ValueError: <default>``.
 
 The rule: any ``int(...)``/``float(...)`` whose argument reads an
 environment variable named ``KT_*`` (``os.environ.get``, ``os.getenv``,

@@ -4,9 +4,8 @@ The whole device story rests on *one compiled program per ladder rung*:
 arrays are padded to pow2/capacity sizes (``_next_pow2``, the
 ``ensure_capacity`` ladders) so object churn never changes array shapes
 and XLA never recompiles on the serving path (a recompile is a
-100ms-class stall — the 2,311 ms TPU ticks in BENCH_TPU_LATEST are
-dispatch/retrace-dominated). This checker pins the host→device boundary
-shape discipline; the runtime half (``utils/retrace.py``,
+100ms-class stall). This checker pins the host→device boundary shape
+discipline; the runtime half (``utils/retrace.py``,
 ``KT_JIT_RETRACE_BUDGET``) counts actual XLA compilations per entry and
 fails a tick that recompiles after warmup.
 

@@ -342,7 +342,7 @@ class ThrottleController(ControllerBase):
 
         With a device manager the classification runs as one kernel call
         over the mirrored tensors; otherwise — or while the device circuit
-        breaker is open after a dispatch failure (backend/tunnel death) —
+        breaker is open after a dispatch failure (backend death) —
         the host oracle loops, so a device outage degrades latency, never
         availability. An accel-class pod takes the host oracle whenever
         any mirrored throttle declares accelClassThresholds: the device

@@ -23,8 +23,11 @@ replay distinguishes two cases:
   not abort on one bad line when every event after it is intact, and the
   health probe surfaces the loss.
 
-When the live log exceeds ``compact_after`` lines it is compacted to a
-snapshot of ADDED events (written to a temp file, atomically renamed); a
+When ``compact_after`` lines have been appended since the last
+compaction the log is compacted to a snapshot of ADDED events (written
+to a temp file, atomically renamed) — counted from the compaction, not
+from the top of the file, so a store holding more objects than
+``compact_after`` does not compact again on every append; a
 failed compaction (fsync/rename error) is logged and retried a window
 later — it never breaks the store's dispatch. Because compaction
 invalidates every existing snapshot's recorded journal anchor, a bound
@@ -670,7 +673,6 @@ class StoreJournal:
         )
         new_sha = hashlib.sha256()
         new_bytes = 0
-        lines = len(objs)
         try:
             with os.fdopen(fd, "w", encoding="utf-8") as f:
                 if epoch > 0:
@@ -683,7 +685,6 @@ class StoreJournal:
                     f.write(data.decode("utf-8"))
                     new_sha.update(data)
                     new_bytes += len(data)
-                    lines += 1
                 for group, entry in sorted(self.gang_ops.items()):
                     # compaction must not erase an in-flight gang reserve
                     # either (protocol checker: control types survive the
@@ -700,7 +701,6 @@ class StoreJournal:
                     f.write(data.decode("utf-8"))
                     new_sha.update(data)
                     new_bytes += len(data)
-                    lines += 1
                 for pid, entry in sorted(self.preempt_ops.items()):
                     # in-flight preemptions survive compaction the same way
                     # (protocol checker: control types survive the
@@ -718,7 +718,6 @@ class StoreJournal:
                     f.write(data.decode("utf-8"))
                     new_sha.update(data)
                     new_bytes += len(data)
-                    lines += 1
                 for kind, obj in objs:
                     data = (
                         json.dumps(
@@ -748,7 +747,7 @@ class StoreJournal:
         maybe_crash(self.faults, "crash.journal.compact")
         self._file.close()
         self._file = open(self.path, "a", encoding="utf-8")
-        self._lines = lines
+        self._lines = 0  # the next window starts at the compacted base
         self._sha = new_sha
         self._bytes = new_bytes
         logger.info("journal %s compacted to %d objects", self.path, len(objs))

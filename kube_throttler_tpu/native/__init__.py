@@ -9,9 +9,11 @@ toolchain is available, so the package never hard-depends on the binary.
 
 Build destination: the package directory when writable (dev checkouts);
 otherwise a per-user cache dir (``$XDG_CACHE_HOME/kube-throttler-tpu``,
-mode 0700) keyed by the source hash — a read-only site-packages install
-builds ONCE per user instead of failing the in-package write and
-re-attempting g++ in every process.
+mode 0700) — a read-only site-packages install builds ONCE per user
+instead of failing the in-package write and re-attempting g++ in every
+process. Both are named by the source hash (``_ktnative-<sha16>.so``), so
+a library built from other source — a stale untracked copy carried along
+with a tree, whatever its mtime — is never loaded.
 
 Set ``KT_TPU_NO_NATIVE=1`` to force the Python tier (used by parity tests).
 """
@@ -31,7 +33,6 @@ import numpy as np
 
 _PKG_DIR = Path(__file__).resolve().parent
 _SRC = _PKG_DIR / "ktnative.cpp"
-_SO = _PKG_DIR / "_ktnative.so"
 
 _lib: Optional[ctypes.CDLL] = None
 from ..utils.lockorder import make_lock as _make_lock
@@ -47,19 +48,28 @@ logger = logging.getLogger(__name__)
 CXX_FLAGS = ["-O3", "-std=c++17", "-shared", "-fPIC"]
 
 
-def _user_cache_so() -> Optional[Path]:
-    """Per-user build destination for read-only installs, keyed by source
-    hash so a package upgrade invalidates stale binaries automatically."""
+def package_so_path() -> Optional[Path]:
+    """In-package build destination for the current source,
+    ``_ktnative-<sha16>.so`` (the Dockerfile installs its prebuilt library
+    here); None without the source."""
     import hashlib
 
     try:
         digest = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]
+    except OSError:
+        return None
+    return _PKG_DIR / f"_ktnative-{digest}.so"
+
+
+def _user_cache_so(name: str) -> Optional[Path]:
+    """Per-user build destination for read-only installs."""
+    try:
         base = Path(
             os.environ.get("XDG_CACHE_HOME") or (Path.home() / ".cache")
         )
         cache_dir = base / "kube-throttler-tpu"
         cache_dir.mkdir(mode=0o700, parents=True, exist_ok=True)
-        return cache_dir / f"_ktnative-{digest}.so"
+        return cache_dir / name
     except OSError:
         return None
 
@@ -152,17 +162,16 @@ def load() -> Optional[ctypes.CDLL]:
         if _load_attempted:
             return _lib
         _load_attempted = True
-        so = _SO
-        fresh = so.exists() and not (
-            _SRC.exists() and so.stat().st_mtime < _SRC.stat().st_mtime
-        )
-        if not fresh:
+        so = package_so_path()
+        if so is None:
+            return None
+        if not so.exists():
             if os.access(_PKG_DIR, os.W_OK):
-                if not _build(_SO):
+                if not _build(so):
                     return None
             else:
                 # read-only install: build (once) into the per-user cache
-                cached = _user_cache_so()
+                cached = _user_cache_so(so.name)
                 if cached is None:
                     return None
                 if not cached.exists() and not _build(cached):
@@ -173,10 +182,9 @@ def load() -> Optional[ctypes.CDLL]:
             _declare(lib)
             _lib = lib
         except (OSError, AttributeError) as exc:
-            # AttributeError: a prebuilt .so predating a symbol added to
-            # _declare (archive extraction can set mtimes that defeat the
-            # source-mtime freshness check) — degrade to the Python tier
-            # like any other load failure instead of crashing the caller
+            # AttributeError: a library missing a symbol _declare names
+            # — degrade to the Python tier like any other load failure
+            # instead of crashing the caller
             logger.warning(
                 "native selector engine load failed (%s); falling back to the "
                 "pure-Python row-match tier",

@@ -342,7 +342,7 @@ void ktn_match_row(void* h, int32_t pod_ns, int32_t ns_exists,
 // Single-pod 4-step classification over K gathered throttle columns — the
 // native tier of the serving hot path (devicestate.check_pod's host route on
 // accelerator backends, where a per-decision device dispatch would cost a
-// full tunnel round trip).  Semantics are a line-for-line mirror of
+// full dispatch round trip).  Semantics are a line-for-line mirror of
 // devicestate._host_classify_rows / ops.check._classify_core (reference
 // check_throttled_for, throttle_types.go:128-153):
 //   1. pod alone exceeds threshold        → 3 (POD_EXCEEDS; onEqual=false)

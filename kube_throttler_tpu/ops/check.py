@@ -274,8 +274,7 @@ try:
         os.environ.get("KT_GATHER_CHUNK_ELEMS", str(64 * 1024 * 1024))
     )
 except ValueError:
-    # a malformed override must not kill module import (the tpu_watch.py
-    # KT_TUNNEL_PROBE_PORT guard, for the same reason); fall back to the
+    # a malformed override must not kill module import; fall back to the
     # 64M default
     _GATHER_CHUNK_ELEMS = 64 * 1024 * 1024
 

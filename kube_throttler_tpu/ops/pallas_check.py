@@ -48,7 +48,9 @@ from .schema import PodBatch
 BP = 256  # pod rows per block (sublane axis)
 BT = 512  # throttle cols per block (lane axis)
 
-_BIAS = jnp.int32(-(2**31))  # xor bias turning unsigned order into signed
+# xor bias turning unsigned order into signed; numpy, so importing this
+# module builds no device array and initializes no backend
+_BIAS = np.int32(-(2**31))
 
 
 def _split_limbs(x: jnp.ndarray):

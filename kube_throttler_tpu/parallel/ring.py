@@ -32,9 +32,8 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ..utils.jaxcompat import shard_map
 
 from ..ops.aggregate import throttled_flags
 from ..ops.check import CHECK_ACTIVE, CHECK_INSUFFICIENT, CHECK_POD_EXCEEDS, _classify

@@ -27,9 +27,8 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ..utils.jaxcompat import shard_map
 
 from dataclasses import fields as _dc_fields
 

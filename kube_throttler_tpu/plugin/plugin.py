@@ -651,7 +651,8 @@ class KubeThrottler:
         tile partials as the only cross-device traffic.
 
         Returns ``{"schedulable": {pod_key: bool}, "used": {kind:
-        {throttle_key: pod_count}}, "mesh": [dp, tp], "errors": [...]}``.
+        {throttle_key: pod_count}}, "mesh": [dp, tp], "devices": [device
+        ids holding the verdicts], "errors": [...]}``.
         Unlike ``pre_filter_batch`` this classifies against the
         freshly-derived state, not the written statuses (ahead of them
         under churn).
@@ -683,6 +684,7 @@ class KubeThrottler:
                 "schedulable": schedulable,
                 "used": used,
                 "mesh": [mesh.shape["pods"], mesh.shape["throttles"]],
+                "devices": self.device_manager.last_tick_devices,
                 "errors": errors,
             }
 

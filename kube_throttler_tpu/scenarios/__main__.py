@@ -47,7 +47,7 @@ def _run_isolated(name: str, seed: int, workdir: str, regression=None):
     if regression:
         cmd += ["--regression", regression]
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(cmd, capture_output=True, text=True, timeout=1200, env=env)
     report_path = os.path.join(workdir, f"report-{name}-s{seed}.json")
     report = None
@@ -180,7 +180,7 @@ def main(argv=None) -> int:
                 "--workdir", wd,
             ]
             env = dict(os.environ)
-            env.setdefault("JAX_PLATFORMS", "cpu")
+            env["JAX_PLATFORMS"] = "cpu"
             proc = subprocess.run(
                 cmd, capture_output=True, text=True, timeout=1200, env=env
             )

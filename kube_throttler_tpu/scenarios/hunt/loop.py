@@ -135,7 +135,7 @@ class SubprocessEvaluator:
         with open(program_path, "w") as f:
             json.dump(scenario_to_dict(scn), f, indent=2)
         env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        env["JAX_PLATFORMS"] = "cpu"
         # the hunt DETECTS regressions by latency gates failing through
         # the real stack; the slow-host advisory calibration in
         # scenarios/slo.py would blind it, so evals always enforce

@@ -193,3 +193,28 @@ def flip_band_mc(P: int, groups: int) -> int:
     """Expected group cpu sum in milli: P/groups pods × the 400m mean of
     the churn generator's rng.randrange(1, 8) * 100 distribution."""
     return round(P / groups * 400)
+
+
+def served_state(P: int, T: int, groups: int = 500, seed: int = 0):
+    """The served-stack state bench.py and chip_smoke.py load: T
+    ``served_throttle`` objects (flip band on) and P bound Running pods,
+    each in group g{0..groups-1} with a 100m-700m cpu request, drawn
+    from ``random.Random(seed)``. Returns (throttles, pods)."""
+    import random
+
+    from ..api.pod import make_pod
+
+    flip_mc = flip_band_mc(P, groups)
+    throttles = [served_throttle(i, groups, flip_band_mc=flip_mc) for i in range(T)]
+    rng = random.Random(seed)
+    pods = [
+        make_pod(
+            f"p{i}",
+            labels={"grp": f"g{rng.randrange(groups)}"},
+            requests={"cpu": f"{rng.randrange(1, 8) * 100}m"},
+            node_name="node-1",
+            phase="Running",
+        )
+        for i in range(P)
+    ]
+    return throttles, pods

@@ -36,6 +36,7 @@ from .api.pod import Namespace
 from .api.serialization import object_from_dict
 from .api.types import ClusterThrottle, Throttle
 from .utils import tracing
+from .utils.platform import device_info
 from .engine.store import NotFoundError, Store
 from .plugin import KubeThrottler
 
@@ -260,6 +261,9 @@ class ThrottlerHTTPServer:
                         "enabled": True,
                         "available": dm.device_available(),
                         "breaker": dm.breaker_state(),
+                        # platform, kind, count and memory as JAX
+                        # reports them (utils/platform.device_info)
+                        **device_info(),
                     }
                 ),
                 # the sharded front has no local controllers — its

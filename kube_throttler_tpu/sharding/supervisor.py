@@ -170,7 +170,9 @@ class ShardSupervisor:
 
     def _child_env(self) -> dict:
         env = dict(os.environ if self.env is None else self.env)
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        # fleet workers are host-only: a chip belongs to one process, so
+        # N workers under an ambient JAX_PLATFORMS=tpu would contend for it
+        env["JAX_PLATFORMS"] = "cpu"
         if self.auth_key is not None:
             env["KT_SHARD_AUTH_KEY"] = self.auth_key.decode("utf-8")
         # rolling-upgrade skew knobs (version.py): reach children even

@@ -1298,9 +1298,6 @@ def listen_requires_auth(host: str) -> bool:
 
 
 def main(argv: Optional[List[str]] = None) -> int:
-    from ..utils.platform import honor_jax_platforms_env
-
-    honor_jax_platforms_env()
     parser = argparse.ArgumentParser(prog="kube-throttler-shard")
     parser.add_argument("--shard-id", type=int, required=True)
     parser.add_argument("--shards", type=int, required=True)

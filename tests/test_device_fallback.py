@@ -1,4 +1,4 @@
-"""Device circuit breaker: a failed device dispatch (tunnel drop, backend
+"""Device circuit breaker: a failed device dispatch (backend
 death) must degrade LATENCY, never availability — decisions and reconciles
 fall back to the host-oracle paths, the breaker skips the device for a
 cooldown, and service resumes on the device after it.
@@ -76,7 +76,7 @@ def _break_device(dm, method):
 
     def boom(*a, **k):
         calls.append(1)
-        raise _Boom("tunnel died")
+        raise _Boom("backend died")
 
     setattr(dm, method, boom)
     return calls
@@ -186,7 +186,7 @@ class TestFlakyDeviceSoak:
         def flaky(real):
             def f(*a, **k):
                 if down[0]:
-                    raise RuntimeError("injected tunnel failure")
+                    raise RuntimeError("injected backend failure")
                 return real(*a, **k)
 
             return f

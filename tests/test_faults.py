@@ -586,7 +586,7 @@ class TestBreakerHalfOpen:
 
         def boom():
             calls.append("boom")
-            raise RuntimeError("tunnel died")
+            raise RuntimeError("backend died")
 
         assert dm.guarded("t", ok) == {"fine": True}
         assert dm.guarded("t", boom) is None  # opens

@@ -196,6 +196,27 @@ class TestJournal:
         assert len(recovered.list_pods()) == 1
         assert len(recovered.list_throttles()) == 1
 
+    def test_store_larger_than_compact_after_compacts_once_per_window(
+        self, tmp_path, monkeypatch
+    ):
+        """The compaction window counts appends since the last compaction:
+        a store holding more objects than ``compact_after`` must not
+        rewrite the whole log on every append after its first compaction."""
+        path = str(tmp_path / "store.journal")
+        store = Store()
+        journal = attach(store, path, compact_after=5)
+        runs = []
+        compact = journal._compact_locked
+        monkeypatch.setattr(journal, "_compact_locked", lambda: runs.append(1) or compact())
+        store.create_namespace(Namespace("default"))
+        for i in range(30):
+            store.create_pod(_bound(make_pod(f"p{i}", labels={"grp": "a"})))
+        journal.close()
+        assert len(runs) == 31 // 5
+        recovered = Store()
+        attach(recovered, path).close()
+        assert len(recovered.list_pods()) == 30
+
     def test_daemon_resumes_with_live_state(self, tmp_path):
         """Full loop: daemon writes statuses, 'crashes', a new daemon over
         the same journal serves correct admission immediately."""

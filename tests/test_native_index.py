@@ -66,6 +66,18 @@ def test_native_engine_loads():
     assert NativeRowEngine("throttle") is not None
 
 
+def test_library_is_named_by_source_hash():
+    """A library built from other source (a stale untracked copy carried
+    with a tree, whatever its mtime) is never the one loaded."""
+    import hashlib
+
+    from kube_throttler_tpu import native
+
+    digest = hashlib.sha256(native._SRC.read_bytes()).hexdigest()[:16]
+    assert native.package_so_path().name == f"_ktnative-{digest}.so"
+    assert native.package_so_path().exists()
+
+
 def test_engine_matchlabels_semantics():
     eng = NativeRowEngine("throttle")
     # col 0: ns 7, one term {1:2}; col 1: ns 7, empty selector (no terms)

@@ -51,7 +51,7 @@ def child_env() -> dict:
     (children must never fight over an accelerator mid-matrix)."""
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO_ROOT + os.pathsep + env.get("PYTHONPATH", "")
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     return env
 
 

@@ -415,10 +415,6 @@ def main(argv=None) -> int:
     one.add_argument("--seed", type=int, default=0)
     args = parser.parse_args(argv)
 
-    from kube_throttler_tpu.utils.platform import honor_jax_platforms_env
-
-    honor_jax_platforms_env()
-
     if args.command == "one":
         if args.site.startswith("shm."):
             case = next(

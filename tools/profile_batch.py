@@ -9,9 +9,6 @@ import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-from kube_throttler_tpu.utils.platform import honor_jax_platforms_env  # noqa: E402
-
-honor_jax_platforms_env()
 
 import bench  # noqa: E402
 

@@ -13,9 +13,6 @@ import time
 from dataclasses import replace as dc_replace
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-from kube_throttler_tpu.utils.platform import honor_jax_platforms_env  # noqa: E402
-
-honor_jax_platforms_env()
 
 import bench  # noqa: E402
 from kube_throttler_tpu.api.pod import make_pod  # noqa: E402

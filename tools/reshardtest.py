@@ -240,10 +240,6 @@ def main(argv=None) -> int:
     one.add_argument("--seed", type=int, default=0)
     args = parser.parse_args(argv)
 
-    from kube_throttler_tpu.utils.platform import honor_jax_platforms_env
-
-    honor_jax_platforms_env()
-
     if args.command == "one":
         result = run_case(args.site, args.mode, args.seed)
         print(json.dumps(result, indent=2))

@@ -372,10 +372,6 @@ def main(argv=None) -> int:
     sub.add_parser("smoke", help="reduced-scale CI gate (hack/ci.sh)")
     args = parser.parse_args(argv)
 
-    from kube_throttler_tpu.utils.platform import honor_jax_platforms_env
-
-    honor_jax_platforms_env()
-
     if args.command == "one":
         fn = dict(CASES)[args.case]
         result = fn(args.seed)
