@@ -679,6 +679,11 @@ def main(argv: Optional[list] = None) -> int:
     register_build_metrics(
         metrics_registry, role=("standalone" if _role == "none" else _role)
     )
+    # compile counts from before the prewarm on: a recompile while
+    # serving is what an operator most needs to see
+    from .metrics import register_compile_metrics
+
+    register_compile_metrics(metrics_registry)
     if rest_config is not None:
         from .client.transport import RemoteSession
 

@@ -199,6 +199,9 @@ METRIC_NAMES = frozenset(
         "kube_throttler_build_info",
         "kube_throttler_shard_version_mismatch_total",
         "kube_throttler_shard_restart_backoff_seconds",
+        # XLA work of this process by jax.monitoring event
+        # (register_compile_metrics / utils/platform.py)
+        "kube_throttler_xla_compiles_total",
     }
 )
 
@@ -1230,6 +1233,30 @@ def register_reshard_metrics(registry: Registry, front) -> Dict[str, object]:
         "cutover": cutover_h,
         "aborts": aborts_c,
     }
+
+
+def register_compile_metrics(registry: Registry) -> None:
+    """XLA work since the process started counting, by event: ``trace``
+    (a jaxpr traced: a new program or argument shape), ``compile`` (a
+    backend compile) and ``cache_load`` (a program read back from the
+    persistent compilation cache). After start-up prewarm these should
+    stand still: a count that moves while serving is a recompile on the
+    serving path, paid inside some request's latency."""
+    from .utils.platform import compile_counts, listen_for_compiles
+
+    listen_for_compiles()
+    compiles_c = registry.counter_vec(
+        "kube_throttler_xla_compiles_total",
+        "XLA programs traced, compiled or loaded from the persistent "
+        "compilation cache by this process",
+        ["event"],
+    )
+
+    def flush() -> None:
+        for event, n in compile_counts().items():
+            compiles_c.set_key((event,), float(n))
+
+    registry.register_pre_expose(flush)
 
 
 def register_ingest_metrics(registry: Registry, pipeline) -> None:

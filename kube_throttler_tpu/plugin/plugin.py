@@ -481,7 +481,9 @@ class KubeThrottler:
 
             # host oracle, side-effect-free (no Warning events — triage
             # only, matching the device path)
-            for pod in self.listers.pods.list():
+            with self.tracer.trace("store_list"):
+                pods = self.listers.pods.list()
+            for pod in pods:
                 try:
                     ta, ti, te, _ = self.throttle_ctr.check_throttled(pod, False)
                     ca, ci, ce, _ = self.cluster_throttle_ctr.check_throttled(pod, False)
@@ -510,7 +512,8 @@ class KubeThrottler:
         (no Warning events), unknown-namespace pods land in ``errors``,
         ERROR evaluations route every group member to ``errors``."""
         dm, cache = self.device_manager, self.verdict_cache
-        pods = self.listers.pods.list()
+        with self.tracer.trace("store_list"):
+            pods = self.listers.pods.list()
         if len(pods) > self.BATCH_DEDUPE_MAX_PODS:
             return None
         groups: dict = {}
@@ -628,7 +631,9 @@ class KubeThrottler:
             return
         from ..api.pod import accel_class_of
 
-        for pod in self.listers.pods.list():
+        with self.tracer.trace("store_list"):
+            pods = self.listers.pods.list()
+        for pod in pods:
             if not accel_class_of(pod) or pod.key not in schedulable:
                 continue
             try:

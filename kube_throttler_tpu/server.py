@@ -25,8 +25,10 @@ concurrency is whatever the plugin already guarantees.
 
 from __future__ import annotations
 
+import itertools
 import json
 import threading
+import time
 from dataclasses import replace
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional, Tuple
@@ -39,6 +41,37 @@ from .utils import tracing
 from .utils.platform import device_info
 from .engine.store import NotFoundError, Store
 from .plugin import KubeThrottler
+
+
+# routes that get a span of their own, ``http_<route>``: the last path
+# segment with ``-`` made ``_``; any other path is ``http_other``, so a
+# stray URL cannot grow the phase label's cardinality
+_ROUTE_PHASES = {
+    route: "http_" + route.replace("-", "_")
+    for route in (
+        "healthz", "readyz", "metrics", "objects", "throttles",
+        "clusterthrottles", "pods", "prefilter", "prefilter-batch", "tick",
+        "reserve", "unreserve", "bind", "v",
+    )
+}
+
+
+def _route_phase(path: str) -> str:
+    return _ROUTE_PHASES.get(path.partition("?")[0].rpartition("/")[2], "http_other")
+
+
+class _HTTPServer(ThreadingHTTPServer):
+    """Stamps each connection as the accept loop hands it to its handler
+    thread; the handler's first request observes the wait from there
+    (thread start plus request-line and header parse) as ``http_wait``."""
+
+    def __init__(self, *args, **kwargs):
+        self.accepted = {}  # socket -> perf_counter at hand-off
+        super().__init__(*args, **kwargs)
+
+    def process_request(self, request, client_address):
+        self.accepted[request] = time.perf_counter()
+        super().process_request(request, client_address)
 
 
 def _throttle_to_dict(thr) -> dict:
@@ -133,57 +166,91 @@ class ThrottlerHTTPServer:
         # /healthz stays 200 — the process is alive and must not be killed
         # mid-flush.
         self._draining = False
+        # the request spans share the plugin's histogram family (one
+        # registry in the daemon); a request id ties a trace's spans
+        # of one request together
+        self.tracer = (
+            tracing.PhaseTracer(self.metrics_registry)
+            if self.metrics_registry is not None
+            else tracing.NoopTracer()
+        )
+        self._request_ids = itertools.count()
         outer = self
 
         class Handler(BaseHTTPRequestHandler):
             def log_message(self, fmt, *args):  # quiet
                 pass
 
-            def _send(self, code: int, body, content_type="application/json"):
-                data = (
-                    body.encode() if isinstance(body, str) else json.dumps(body).encode()
+            def setup(self):
+                super().setup()
+                self._accepted = self.server.accepted.pop(self.request, None)
+
+            def _span(self):
+                """The request's ``http_<route>`` span. The connection's
+                first request also observes its wait for a handler."""
+                wait_us = 0
+                if self._accepted is not None:
+                    wait = time.perf_counter() - self._accepted
+                    self._accepted = None  # later keep-alive requests did not wait
+                    outer.tracer.observe("http_wait", wait)
+                    wait_us = int(wait * 1e6)
+                return outer.tracer.trace(
+                    _route_phase(self.path),
+                    rid=next(outer._request_ids),
+                    wait_us=wait_us,
                 )
-                self.send_response(code)
-                self.send_header("Content-Type", content_type)
-                self.send_header("Content-Length", str(len(data)))
-                self.end_headers()
-                self.wfile.write(data)
+
+            def _send(self, code: int, body, content_type="application/json"):
+                with outer.tracer.trace("http_encode"):
+                    data = (
+                        body.encode() if isinstance(body, str) else json.dumps(body).encode()
+                    )
+                    self.send_response(code)
+                    self.send_header("Content-Type", content_type)
+                    self.send_header("Content-Length", str(len(data)))
+                    self.end_headers()
+                    self.wfile.write(data)
 
             def _body(self) -> dict:
-                length = int(self.headers.get("Content-Length", "0"))
-                if length == 0:
-                    return {}
-                return json.loads(self.rfile.read(length))
+                with outer.tracer.trace("http_decode"):
+                    length = int(self.headers.get("Content-Length", "0"))
+                    if length == 0:
+                        return {}
+                    return json.loads(self.rfile.read(length))
 
             def do_GET(self):
-                try:
-                    outer._get(self)
-                except Exception as e:  # pragma: no cover
-                    self._send(500, {"error": str(e)})
+                with self._span():
+                    try:
+                        outer._get(self)
+                    except Exception as e:  # pragma: no cover
+                        self._send(500, {"error": str(e)})
 
             def do_POST(self):
-                try:
-                    outer._post(self)
-                except NotFoundError as e:
-                    self._send(404, {"error": str(e)})
-                except Exception as e:
-                    self._send(400, {"error": str(e)})
+                with self._span():
+                    try:
+                        outer._post(self)
+                    except NotFoundError as e:
+                        self._send(404, {"error": str(e)})
+                    except Exception as e:
+                        self._send(400, {"error": str(e)})
 
             def do_DELETE(self):
-                try:
-                    outer._delete(self)
-                except NotFoundError as e:
-                    self._send(404, {"error": str(e)})
-                except Exception as e:
-                    self._send(400, {"error": str(e)})
+                with self._span():
+                    try:
+                        outer._delete(self)
+                    except NotFoundError as e:
+                        self._send(404, {"error": str(e)})
+                    except Exception as e:
+                        self._send(400, {"error": str(e)})
 
             def do_PUT(self):
-                try:
-                    outer._put(self)
-                except Exception as e:
-                    self._send(400, {"error": str(e)})
+                with self._span():
+                    try:
+                        outer._put(self)
+                    except Exception as e:
+                        self._send(400, {"error": str(e)})
 
-        self._httpd = ThreadingHTTPServer((host, port), Handler)
+        self._httpd = _HTTPServer((host, port), Handler)
         self.port = self._httpd.server_address[1]
         self._thread: Optional[threading.Thread] = None
 
@@ -312,11 +379,11 @@ class ThrottlerHTTPServer:
             h._send(404, {"error": f"unknown path {h.path}"})
 
     def _resolve_pod(self, body: dict):
-        if "podKey" in body:
-            namespace, _, name = body["podKey"].partition("/")
-            return self.store.get_pod(namespace, name)
-        pod = object_from_dict(body)
-        return pod
+        with self.tracer.trace("http_decode"):
+            if "podKey" in body:
+                namespace, _, name = body["podKey"].partition("/")
+                return self.store.get_pod(namespace, name)
+            return object_from_dict(body)
 
     _REMOTE_REFUSAL = (
         "this daemon mirrors a remote apiserver (kubeconfig mode); local "
@@ -513,6 +580,7 @@ class ThrottlerHTTPServer:
         self.listers = plugin.listers
         if self.metrics_registry is None:
             self.metrics_registry = plugin.metrics_registry
+            self.tracer = tracing.PhaseTracer(self.metrics_registry)
 
     def mark_draining(self) -> None:
         """Flip /readyz to 503 (graceful shutdown step 1) while keeping the

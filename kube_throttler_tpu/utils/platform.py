@@ -12,15 +12,25 @@ import time
 from pathlib import Path
 from typing import Dict, Optional
 
+from .lockorder import make_lock
+
 CHECKOUT_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 
-# JAX monitoring events this process has seen (cache_stats reads them)
-_CACHE_EVENTS = {
+# JAX monitoring events this process has seen (cache_stats and
+# compile_counts read them); the listeners are registered once per process
+_EVENTS = {
     "/jax/compilation_cache/compile_requests_use_cache": "requests",
     "/jax/compilation_cache/cache_hits": "hits",
 }
-_cache_counts: Dict[str, int] = {v: 0 for v in _CACHE_EVENTS.values()}
-_listening: list = []  # non-empty once the listener is registered
+_DURATION_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+_counts: Dict[str, int] = {
+    name: 0 for name in (*_EVENTS.values(), *_DURATION_EVENTS.values())
+}
+_counts_lock = make_lock("platform.compile_counts")
+_listening: list = []  # non-empty once the listeners are registered
 
 
 def compilation_cache_dir() -> str:
@@ -28,10 +38,29 @@ def compilation_cache_dir() -> str:
     return os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(CHECKOUT_CACHE_DIR)
 
 
-def _count_cache_event(event: str, **_kw) -> None:
-    name = _CACHE_EVENTS.get(event)
+def _count(name) -> None:
     if name is not None:
-        _cache_counts[name] += 1
+        with _counts_lock:
+            _counts[name] += 1
+
+
+def _count_event(event: str, **_kw) -> None:
+    _count(_EVENTS.get(event))
+
+
+def _count_duration_event(event: str, _secs: float, **_kw) -> None:
+    _count(_DURATION_EVENTS.get(event))
+
+
+def listen_for_compiles() -> None:
+    """Count JAX's compile events from here on (idempotent)."""
+    import jax
+
+    with _counts_lock:
+        if not _listening:
+            jax.monitoring.register_event_listener(_count_event)
+            jax.monitoring.register_event_duration_secs_listener(_count_duration_event)
+            _listening.append(True)
 
 
 def enable_compilation_cache(platform: str) -> Optional[str]:
@@ -55,16 +84,24 @@ def enable_compilation_cache(platform: str) -> Optional[str]:
     # scatter/gather shapes whose compile times sit under the default
     # min-compile-time threshold
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    if not _listening:
-        jax.monitoring.register_event_listener(_count_cache_event)
-        _listening.append(True)
+    listen_for_compiles()
     return path
 
 
 def cache_stats() -> Dict[str, int]:
     """{"requests", "hits"}: compiles that consulted the persistent cache
     since enable_compilation_cache, and how many it answered."""
-    return dict(_cache_counts)
+    with _counts_lock:
+        return {"requests": _counts["requests"], "hits": _counts["hits"]}
+
+
+def compile_counts() -> Dict[str, int]:
+    """{"trace", "compile", "cache_load"} since listen_for_compiles: jaxpr
+    traces (a new program or shape), backend compiles, and programs loaded
+    from the persistent cache instead of compiled."""
+    with _counts_lock:
+        return {"trace": _counts["trace"], "compile": _counts["compile"],
+                "cache_load": _counts["hits"]}
 
 
 def device_info() -> dict:

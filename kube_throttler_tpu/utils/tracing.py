@@ -10,23 +10,35 @@ equivalent here is richer, per the survey's prescription:
   the same registry that serves ``/metrics``; phases cover the scheduling
   hot path (prefilter/reserve/unreserve), the async state engine
   (reconcile), and host↔device sync.
+  While a ``jax.profiler`` session runs, every span is also written into
+  the profiler's trace as a ``kt.<phase>`` host event (nested per thread,
+  optional ``#key=value#`` stats), on the same clock as the device's
+  operations — so a device idle gap can be named by the host work under
+  it. Outside a session a span costs one ``is_enabled`` check more.
 - klog-style verbosity: :func:`set_verbosity` / :func:`v_enabled` /
   :func:`vlog` map V-levels onto the stdlib logger the way klog maps them
   onto --v (V(2)≈INFO detail … V(5)≈trace). The daemon's
   ``PUT /debug/flags/v`` analog calls ``set_verbosity`` at runtime.
-- :func:`device_trace` — context manager around ``jax.profiler.trace``
-  for Perfetto/XProf kernel traces (jax imported lazily; no-op when
-  profiling is unavailable).
 """
 
 from __future__ import annotations
 
-import contextlib
 import logging
 import time
-from typing import Dict, Iterator, Optional
+from typing import Dict, Optional
 
 from .lockorder import make_lock
+
+try:
+    from jax.profiler import TraceAnnotation
+
+    _profiling = TraceAnnotation.is_enabled
+except ImportError:  # a jaxlib without the profiler: spans reach the histogram only
+    TraceAnnotation = None
+
+    def _profiling() -> bool:
+        return False
+
 
 logger = logging.getLogger("kube_throttler_tpu")
 
@@ -76,11 +88,13 @@ class PhaseTracer:
             ["phase"],
         )
 
-    def trace(self, phase: str) -> "_Trace":
+    def trace(self, phase: str, **stats) -> "_Trace":
+        """Time the enclosed block as ``phase``. ``stats`` (e.g. a request
+        id) ride on the profiler event only; the histogram ignores them."""
         # a slotted context object, not @contextmanager: the generator
         # protocol costs ~3µs per enter/exit and the serving hot path
         # crosses 6+ trace scopes per decision
-        return _Trace(self._hist, phase)
+        return _Trace(self._hist, phase, stats)
 
     def observe(self, phase: str, seconds: float) -> None:
         self._hist.observe({"phase": phase}, seconds)
@@ -96,22 +110,27 @@ class PhaseTracer:
 
 class _Trace:
     """Slotted timing scope: observes phase duration into the histogram
-    family on exit (plus V(5) logging when enabled)."""
+    family on exit and, while a profiler session runs, spans the block
+    with a ``kt.<phase>`` trace event."""
 
-    __slots__ = ("_hist", "_phase", "_start")
+    __slots__ = ("_hist", "_phase", "_stats", "_event", "_start")
 
-    def __init__(self, hist, phase: str) -> None:
+    def __init__(self, hist, phase: str, stats: dict) -> None:
         self._hist = hist
         self._phase = phase
+        self._stats = stats
 
     def __enter__(self) -> None:
+        self._event = None
+        if _profiling():
+            self._event = TraceAnnotation("kt." + self._phase, **self._stats)
+            self._event.__enter__()
         self._start = time.perf_counter()
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        elapsed = time.perf_counter() - self._start
-        self._hist.observe_key((self._phase,), elapsed)
-        if v_enabled(5):
-            vlog(5, "phase %s took %.6fs", self._phase, elapsed)
+        self._hist.observe_key((self._phase,), time.perf_counter() - self._start)
+        if self._event is not None:
+            self._event.__exit__(exc_type, exc, tb)
 
 
 class _NoopHist:
@@ -132,26 +151,3 @@ class NoopTracer(PhaseTracer):
     def __init__(self) -> None:  # deliberately no super().__init__
         self._hist = _NoopHist()
 
-
-@contextlib.contextmanager
-def device_trace(log_dir: str) -> Iterator[None]:
-    """Capture a JAX profiler trace (XProf/Perfetto) for the enclosed
-    block. No-op if the profiler cannot start (e.g. unsupported backend)."""
-    started = False
-    try:
-        import jax
-
-        jax.profiler.start_trace(log_dir)
-        started = True
-    except Exception as e:  # pragma: no cover — backend-dependent
-        logger.warning("device_trace unavailable: %s", e)
-    try:
-        yield
-    finally:
-        if started:
-            try:
-                import jax
-
-                jax.profiler.stop_trace()
-            except Exception as e:  # pragma: no cover
-                logger.warning("stop_trace failed: %s", e)
