@@ -169,6 +169,11 @@ class Indexer:
                 return list(self._meta.keys())
             return list(self._objects.keys())
 
+    def count(self) -> int:
+        """Cached objects, counted without reading (or resolving) any."""
+        with self._lock:
+            return len(self._meta if self._resolver is not None else self._objects)
+
     def snapshot(self) -> Dict[str, object]:
         """Keyed copy of the cache under one lock hold (recovery's
         first-relist reconcile walks this rather than the raw store)."""

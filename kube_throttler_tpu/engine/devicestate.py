@@ -2442,6 +2442,63 @@ class DeviceStateManager:
                     return key, esum
             return self._build_fingerprint_locked(pod)
 
+    def verdict_fingerprints(self, pods: Sequence[Pod]) -> List[Optional[Tuple[tuple, int]]]:
+        """``verdict_fingerprint`` of each of ``pods`` — the same keys and
+        epoch sums — from one read of each index's match rows under one
+        hold of the main lock, instead of a dense mask row per pod. The
+        batch dedupe fingerprints every stored pod with it; listed pods
+        are fresh objects, so the per-object memo is neither read nor
+        filled. A pod that either index holds no row for takes the
+        single-pod build."""
+        from ..api.pod import accel_class_of
+
+        tks, cks = self.throttle, self.clusterthrottle
+        arena = tks.arena
+        out: List[Optional[Tuple[tuple, int]]] = [None] * len(pods)
+        if arena is None:
+            return out
+        with self._lock:
+            known: Dict[str, bool] = {}
+            heads = []  # (position, shape id, accel class) of keyable pods
+            for i, pod in enumerate(pods):
+                ns = pod.namespace
+                has = known.get(ns)
+                if has is None:
+                    has = known[ns] = cks.index.has_namespace(ns)
+                if not has:
+                    continue
+                if pod.__dict__.get("_kt_arena") is arena.token:
+                    sid = pod.__dict__["_kt_req_sid"]
+                else:
+                    sid = arena.request_shape_id(pod.spec)
+                heads.append((i, sid, accel_class_of(pod)))
+            keys = [pods[i].key for i, _, _ in heads]
+            kinds = []
+            for ks in (tks, cks):
+                ks.ensure_capacity()
+                has, block, icap = ks.index.pods_match_cols(keys)
+                n = min(icap, ks.tcap)
+                live = block < n
+                at = np.where(live, block, 0)
+                keep = live & ks.thr_valid[at]
+                esum = np.where(keep, ks.col_epoch[at], 0).sum(axis=1) + ks.global_epoch
+                cols = np.where(keep, block, np.iinfo(np.int32).max)
+                cols.sort(axis=1)
+                # each row's kept prefix as np.nonzero's intp bytes
+                width = cols.shape[1] * np.dtype(np.intp).itemsize
+                counts = keep.sum(axis=1) * np.dtype(np.intp).itemsize
+                kinds.append((has.tolist(), cols.astype(np.intp).tobytes(), width,
+                              counts.tolist(), esum.tolist()))
+            (t_has, t_raw, t_w, t_n, t_esum), (c_has, c_raw, c_w, c_n, c_esum) = kinds
+            for j, (i, sid, accel) in enumerate(heads):
+                if not (t_has[j] and c_has[j]):
+                    out[i] = self._build_fingerprint_locked(pods[i])
+                    continue
+                t0, c0 = j * t_w, j * c_w
+                key = (sid, accel, t_raw[t0 : t0 + t_n[j]], c_raw[c0 : c0 + c_n[j]])
+                out[i] = (key, t_esum[j] + c_esum[j])
+        return out
+
     def _build_fingerprint_locked(self, pod: Pod) -> Optional[Tuple[tuple, int]]:
         from ..api.pod import accel_class_of
 

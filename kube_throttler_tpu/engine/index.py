@@ -965,6 +965,20 @@ class SelectorIndex:
         with self._lock:
             return self._pod_rows.get(pod_key)
 
+    def pods_match_cols(self, pod_keys: Sequence[str]) -> Tuple[np.ndarray, np.ndarray, int]:
+        """(has-a-row mask, sorted matched cols of each key's row, sentinel-
+        padded, tcap) under one lock hold: what ``mask_rows`` gives for
+        many pods, read without a dense row per pod. Keys without a row
+        read all sentinels."""
+        with self._lock:
+            get = self._pod_rows.get
+            rows = np.fromiter((get(k, -1) for k in pod_keys), dtype=np.int64,
+                               count=len(pod_keys))
+            has = rows >= 0
+            block = self._row_cols[np.where(has, rows, 0)]
+            block[~has] = _SENT
+            return has, block, self._tcap
+
     def throttle_col(self, throttle_key: str) -> Optional[int]:
         with self._lock:
             return self._thr_cols.get(throttle_key)

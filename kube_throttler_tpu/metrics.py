@@ -185,6 +185,9 @@ METRIC_NAMES = frozenset(
         "kube_throttler_verdict_cache_misses_total",
         "kube_throttler_verdict_cache_entries",
         "kube_throttler_verdict_cache_invalidations_total",
+        # pre_filter_batch's host dedupe attempts by outcome
+        # (register_batch_dedupe_metrics / plugin/plugin.py)
+        "kube_throttler_batch_dedupe_total",
         # read-replica admission tier (register_replica_metrics /
         # engine/replication.py ReplicaGate): verdicts served by role and
         # requests refused for breaching the staleness bound — the
@@ -1365,6 +1368,19 @@ def register_verdict_cache_metrics(registry: Registry, cache) -> None:
         inval_c.set_key((), float(invalidations))
 
     registry.register_pre_expose(flush)
+
+
+def register_batch_dedupe_metrics(registry: Registry) -> CounterVec:
+    """``pre_filter_batch``'s host dedupe attempts, one per attempt, by
+    outcome: ``engaged`` (grouped and answered on the host),
+    ``declined_size`` (over the pod limit, from the count: no pod listed
+    or fingerprinted) and ``declined_groups`` (every pod listed and
+    fingerprinted, then too few pods per group)."""
+    return registry.counter_vec(
+        "kube_throttler_batch_dedupe_total",
+        "pre_filter_batch host dedupe attempts by outcome",
+        ["outcome"],
+    )
 
 
 def register_replica_metrics(registry: Registry, gate) -> None:

@@ -24,6 +24,7 @@ from ..metrics import (
     Registry,
     StatusLagMetrics,
     ThrottleMetricsRecorder,
+    register_batch_dedupe_metrics,
     register_breaker_metrics,
     register_watch_metrics,
 )
@@ -263,6 +264,7 @@ class KubeThrottler:
             except ValueError:
                 capacity = 65536  # malformed override must not kill serving
             self.verdict_cache = VerdictCache(capacity=capacity)
+        self._dedupe_outcomes = register_batch_dedupe_metrics(self.metrics_registry)
         # verdict-coherence assassin (utils/epochassert.py): when armed,
         # sampled cache hits are shadow-recomputed through the uncached
         # oracle route — a divergence at an unchanged epoch sum proves a
@@ -508,18 +510,23 @@ class KubeThrottler:
         falls through to the fused device path): mix not degenerate
         enough, or too many pods to fingerprint host-side.
 
+        The informer's pod count decides the size limit before any pod is
+        listed; every listed pod is then fingerprinted in one batch
+        (``verdict_fingerprints``) for the groups test. A decline changes
+        no answer.
+
         Semantics mirror the host-oracle fallback exactly: side-effect-free
         (no Warning events), unknown-namespace pods land in ``errors``,
         ERROR evaluations route every group member to ``errors``."""
         dm, cache = self.device_manager, self.verdict_cache
+        n = self.listers.pods.count()
+        if n > self.BATCH_DEDUPE_MAX_PODS:
+            return self._dedupe_outcome("declined_size")
         with self.tracer.trace("store_list"):
             pods = self.listers.pods.list()
-        if len(pods) > self.BATCH_DEDUPE_MAX_PODS:
-            return None
         groups: dict = {}
         loners: list = []
-        for pod in pods:
-            fp = dm.verdict_fingerprint(pod)
+        for pod, fp in zip(pods, dm.verdict_fingerprints(pods)):
             if fp is None:
                 loners.append(pod)
                 continue
@@ -527,8 +534,8 @@ class KubeThrottler:
             if g is None:
                 groups[fp[0]] = g = (fp[1], [])
             g[1].append(pod)
-        if len(pods) > 256 and len(groups) * 2 > len(pods):
-            return None  # not degenerate — grouping bought nothing
+        if self.grouping_declines(len(groups), len(pods)):
+            return self._dedupe_outcome("declined_groups")
         schedulable: dict = {}
         errors: list = []
         for key, (esum, members) in groups.items():
@@ -561,7 +568,21 @@ class KubeThrottler:
                 errors.append(pod.key)
                 continue
             schedulable[pod.key] = not (ta or ti or te or ca or ci or ce)
-        return {"schedulable": schedulable, "errors": errors}
+        return self._dedupe_outcome(
+            "engaged", {"schedulable": schedulable, "errors": errors}
+        )
+
+    @staticmethod
+    def grouping_declines(groups: int, pods: int) -> bool:
+        """The dedupe's groups test: over 256 pods, more than half as many
+        groups as pods means the mix is not degenerate and grouping buys
+        nothing over the fused device path."""
+        return pods > 256 and groups * 2 > pods
+
+    def _dedupe_outcome(self, outcome: str, result: Optional[dict] = None):
+        """Count one dedupe attempt under ``outcome``; pass ``result`` on."""
+        self._dedupe_outcomes.inc({"outcome": outcome})
+        return result
 
     @staticmethod
     def _merge_verdicts(per_kind: dict, known_ns: set):

@@ -297,6 +297,180 @@ class TestBatchDedupe:
         assert plugin.pre_filter_batch() == out
         plugin.verdict_cache = ref
 
+    # -- the decline from the pod count; the batch fingerprint -------------
+
+    @staticmethod
+    def _outcomes(plugin) -> dict:
+        return {k[0]: v for k, v in plugin._dedupe_outcomes.collect().items()}
+
+    @staticmethod
+    def _population(case, seed, columnar=True):
+        """A stack whose pods make the dedupe route of ``case``: shapes
+        drawn from ``seed``, the groups fixed by the case."""
+        rng = random.Random(seed)
+        store = Store(columnar=columnar)
+        plugin = _plugin(store)
+        store.create_namespace(Namespace("default"))
+        store.create_throttle(_throttle(cpu="30"))
+        store.create_throttle(_throttle("t2", cpu="25", grp="b"))
+        store.create_cluster_throttle(_cluster_throttle(cpu="40"))
+
+        def shapes(k):  # k distinct cpu requests
+            return [f"{m}m" for m in rng.sample(range(1, 5000), k)]
+
+        if case == "degenerate":  # 400 pods over 6 shapes x 2 groups
+            cpus = shapes(6)
+            specs = [("default", "ab"[i % 2], rng.choice(cpus)) for i in range(400)]
+        elif case == "diverse":  # every pod its own shape
+            specs = [("default", "ab"[i % 2], c) for i, c in enumerate(shapes(400))]
+        elif case == "small":  # distinct shapes, but at most 256 pods
+            specs = [("default", "a", c) for c in shapes(200)]
+        elif case == "cols":  # 190 shapes x 2 groups: too many groups, not shapes
+            cpus = shapes(190)
+            specs = [("default", "ab"[(i // 190) % 2], cpus[i % 190]) for i in range(400)]
+        else:  # "ghost": degenerate, plus distinct shapes in a missing namespace
+            cpus = shapes(5)
+            specs = [("default", "a", rng.choice(cpus)) for _ in range(300)]
+            specs += [("ghost", "a", c) for c in shapes(400)]
+        for i, (ns, grp, cpu) in enumerate(specs):
+            store.create_pod(
+                make_pod(f"p{i}", namespace=ns, labels={"grp": grp}, requests={"cpu": cpu})
+            )
+        plugin.run_pending_once()
+        return plugin
+
+    @staticmethod
+    def _full_pass_engages(plugin) -> bool:
+        """The route the list-and-fingerprint pass alone would take."""
+        pods = plugin.listers.pods.list()
+        fps = [plugin.device_manager.verdict_fingerprint(p) for p in pods]
+        groups = {fp[0] for fp in fps if fp is not None}
+        return len(pods) <= plugin.BATCH_DEDUPE_MAX_PODS and not (
+            plugin.grouping_declines(len(groups), len(pods))
+        )
+
+    @pytest.mark.parametrize("seed", [3, 2**31 + 5])
+    @pytest.mark.parametrize(
+        "case,outcome",
+        [
+            ("degenerate", "engaged"),
+            ("diverse", "declined_groups"),
+            ("small", "engaged"),
+            ("cols", "declined_groups"),
+            ("ghost", "engaged"),
+        ],
+    )
+    def test_route_matches_full_fingerprint_pass(self, case, outcome, seed):
+        plugin = self._population(case, seed)
+        engages = self._full_pass_engages(self._population(case, seed))
+        assert engages == (outcome == "engaged")
+        out = plugin.pre_filter_batch()
+        # one count per attempt, under the outcome the full pass implies
+        assert self._outcomes(plugin) == {outcome: 1.0}
+        uncached = self._population(case, seed)
+        uncached.verdict_cache = None
+        assert out == uncached.pre_filter_batch()
+        plugin.pre_filter_batch()
+        assert self._outcomes(plugin) == {outcome: 2.0}
+
+    def test_frozen_dict_store_keeps_the_full_pass(self):
+        # no arena, so no pod takes a key: the route is the full pass's
+        plugin = self._population("diverse", 7, columnar=False)
+        pods = plugin.listers.pods.list()
+        assert plugin.device_manager.verdict_fingerprints(pods) == [None] * 400
+        engages = self._full_pass_engages(self._population("diverse", 7, columnar=False))
+        plugin.pre_filter_batch()
+        assert self._outcomes(plugin) == {
+            ("engaged" if engages else "declined_groups"): 1.0
+        }
+
+    def test_size_limit_declines_before_listing(self):
+        plugin = self._population("degenerate", 11)
+        plugin.BATCH_DEDUPE_MAX_PODS = 100
+        arena = plugin.store.pod_arena
+        made = arena.materializations_total
+        out = plugin.pre_filter_batch()
+        assert self._outcomes(plugin) == {"declined_size": 1.0}
+        assert plugin.tracer.snapshot("store_list") is None
+        assert arena.materializations_total == made
+        # the same answers as the dedupe gives under the default limit
+        engaged = self._population("degenerate", 11)
+        assert engaged.pre_filter_batch() == out
+        assert self._outcomes(engaged) == {"engaged": 1.0}
+
+    @pytest.mark.parametrize("seed", [5, 2**31 + 9])
+    @pytest.mark.parametrize("case", ["degenerate", "diverse", "small", "cols", "ghost"])
+    def test_batch_fingerprints_equal_single(self, case, seed):
+        plugin = self._population(case, seed)
+        dm = plugin.device_manager
+        def fresh():
+            """The stored pods, plus pods no index holds a row for (the
+            single-pod build); new objects each time, so no memo answers."""
+            return plugin.listers.pods.list() + [
+                make_pod(f"new{i}", namespace=ns, labels={"grp": "ab"[i % 2]},
+                         requests={"cpu": f"{i + 1}00m"})
+                for i, ns in enumerate(["default", "default", "ghost"])
+            ]
+
+        def agree():
+            pods = fresh()
+            want = [dm.verdict_fingerprint(p) for p in pods]
+            assert dm.verdict_fingerprints(pods) == want
+            return want
+
+        assert sum(fp is not None for fp in agree()) >= 2
+        # a deleted throttle leaves its column invalid, and status writes
+        # move the epochs: the batch reads both as the single build does
+        plugin.store.delete_throttle("default", "t2")
+        plugin.run_pending_once()
+        agree()
+        # a second throttle over the "a" pods takes the freed column, so
+        # those rows match two: t1's, then t3's
+        plugin.store.create_throttle(_throttle("t3", cpu="20", grp="a"))
+        plugin.run_pending_once()
+        agree()
+        # a column the index still matches but the device state holds
+        # invalid (an upsert in flight) is left out of both
+        ks = dm.throttle
+        ks.thr_valid[ks.index.throttle_col("default/t1")] = False
+        agree()
+
+    def test_dedupe_fingerprints_in_one_batch(self, monkeypatch):
+        plugin = self._population("diverse", 13)
+        dm = plugin.device_manager
+        single, batches = [], []
+        real_one, real_many = dm.verdict_fingerprint, dm.verdict_fingerprints
+        monkeypatch.setattr(dm, "verdict_fingerprint", lambda p: single.append(p) or real_one(p))
+        monkeypatch.setattr(
+            dm, "verdict_fingerprints", lambda ps: batches.append(len(ps)) or real_many(ps)
+        )
+        out = plugin.pre_filter_batch()
+        assert single == [] and batches == [400]
+        assert self._outcomes(plugin) == {"declined_groups": 1.0}
+        assert len(out["schedulable"]) == 400
+        uncached = self._population("diverse", 13)
+        uncached.verdict_cache = None
+        assert uncached.pre_filter_batch() == out
+
+    def test_outcome_counter_is_scraped_from_metrics(self):
+        import urllib.request
+
+        from kube_throttler_tpu.server import ThrottlerHTTPServer
+
+        plugin = self._population("diverse", 17)
+        srv = ThrottlerHTTPServer(plugin, port=0)
+        srv.start()
+        try:
+            base = f"http://127.0.0.1:{srv.port}"
+            req = urllib.request.Request(f"{base}/v1/prefilter-batch", data=b"{}", method="POST")
+            with urllib.request.urlopen(req, timeout=30) as resp:
+                assert resp.status == 200
+            with urllib.request.urlopen(f"{base}/metrics", timeout=30) as resp:
+                text = resp.read().decode()
+        finally:
+            srv.stop()
+        assert 'kube_throttler_batch_dedupe_total{outcome="declined_groups"} 1' in text
+
 
 # --------------------------------------------------------------------------
 # epoch-bump completeness: mutation sweep, cache ≡ recompute
@@ -338,6 +512,10 @@ class TestEpochBumpCompleteness:
                     got = plugin.pre_filter(pod)
                     assert got.code == fresh.code, (pod.key, got.reasons, fresh.reasons)
                     assert norm(got.reasons) == norm(fresh.reasons), pod.key
+            # the batch dedupe's fingerprints agree with the single-pod ones
+            dm = plugin.device_manager
+            listed = plugin.listers.pods.list() + probes
+            assert dm.verdict_fingerprints(listed) == [dm.verdict_fingerprint(p) for p in listed]
 
         def op_throttle_edit():
             name = f"t{rng.randint(0, 3)}"
