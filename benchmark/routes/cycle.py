@@ -12,6 +12,8 @@ import json
 
 import reference
 
+FAULTS = ("altered_answer", "state_unchanged")
+
 
 def drive(d, w, stream, state, start, deadline):
     queue = collections.deque(state["pending"])
@@ -89,3 +91,34 @@ def check(records, ref, say) -> dict:
         f"{above} above the single-count state (a bind counted twice within "
         f"{reference.RECONCILE_S:g}s)")
     return {"prefilter_wrong": (wrong, 0)}
+
+
+def served_reasons(verdict: dict) -> list:
+    """A /v1/prefilter answer's reasons, in the daemon's format."""
+    by = collections.defaultdict(list)
+    for key, state in sorted(verdict.items()):
+        kind = "throttle" if not key.startswith("/") else "clusterthrottle"
+        by[f"{kind}[{state}]"].append(key)
+    return [f"{head}={','.join(keys)}" for head, keys in by.items()]
+
+
+def control(ctl, stream, state, calls) -> list:
+    """The window's records with the reference ``ctl`` in the program's
+    place: ``calls`` PreFilters of the cycle, binding what it admits."""
+    queue = collections.deque(state["pending"])
+    records, t = [], 0.0
+    for _ in range(calls):
+        if not queue:
+            break
+        pk = queue.popleft()
+        verdict = ctl.verdict(pk)
+        code = "UnschedulableAndUnresolvable" if verdict else "Success"
+        raw = json.dumps({"code": code, "reasons": served_reasons(verdict)}).encode()
+        records.append(("prefilter", t, t + 1e-3, True, (pk, raw)))
+        if verdict:
+            queue.append(pk)
+        else:
+            ctl.bind(pk)
+            records.append(("bind", t + 2e-3, t + 3e-3, True, (pk, b"{}")))
+        t += 1e-2
+    return records

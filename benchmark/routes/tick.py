@@ -10,6 +10,7 @@ of its own."""
 import json
 
 PATH = "/v1/tick"
+FAULTS = ("altered_answer", "half_batch")
 
 
 def drive(d, w, stream, state, start, deadline):
@@ -41,3 +42,13 @@ def check(records, ref, say) -> dict:
         used_wrong += judged[raw][1]
     say(f"check: {answers} tick answers ({len(judged)} distinct) against the reference")
     return {"tick_wrong": (wrong, 0), "tick_used_wrong": (used_wrong, 0)}
+
+
+def control(ctl, stream, state, calls) -> list:
+    """One answer of the window from the reference ``ctl`` in the
+    program's place, verdicts and used pod counts."""
+    used = ctl.used_counts()
+    answer = {"schedulable": ctl.schedulable(), "errors": [],
+              "used": {"throttle": {k: v for k, v in used.items() if not k.startswith("/")},
+                       "clusterthrottle": {k: v for k, v in used.items() if k.startswith("/")}}}
+    return [("tick", 0.0, 0.5, True, json.dumps(answer).encode())]

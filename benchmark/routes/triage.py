@@ -16,6 +16,7 @@ import json
 import reference
 
 PATH = "/v1/prefilter-batch"
+FAULTS = ("altered_answer", "half_batch")
 
 
 def drive(d, w, stream, state, start, deadline):
@@ -81,3 +82,10 @@ def check(records, ref, say) -> dict:
     say(f"check: {answers} triage answers ({len(judged)} distinct) against the reference; "
         f"{undecided} pod verdicts left open by binds in flight")
     return {"triage_wrong": (wrong, 0)}
+
+
+def control(ctl, stream, state, calls) -> list:
+    """One answer of the window from the reference ``ctl`` in the
+    program's place."""
+    raw = json.dumps({"schedulable": ctl.schedulable(), "errors": []}).encode()
+    return [("triage", 0.0, 0.5, True, raw)]

@@ -8,6 +8,7 @@ from fractions import Fraction
 import pytest
 
 import reference as R
+import rehearsal
 
 NS = {"kind": "Namespace", "metadata": {"name": "default"}}
 
@@ -135,22 +136,21 @@ def test_check_matches_program_oracle(seed):
             assert got == want, (kind, thr, used, res, req)
 
 
-@pytest.mark.parametrize("gen,cfg", [("grouped_throttles", "tiny-large"),
-                                     ("tenant_clusterthrottles", "tiny-tenant")])
-def test_seeded_states_match_program_oracle(gen, cfg):
-    """Every matched (pod, throttle) state of a small seeded state, as the
-    program's ``api/types.py`` objects compute it."""
+@pytest.mark.parametrize("cfg", [c["name"] for c in rehearsal.configs()])
+def test_seeded_states_match_program_oracle(cfg):
+    """Every matched (pod, throttle) state of a small seeded state of each
+    configuration's generator at its tiny size, as the program's
+    ``api/types.py`` objects compute it."""
     import importlib
-    import os
 
     from kube_throttler_tpu.api.serialization import object_from_dict
     from kube_throttler_tpu.api.types import ResourceAmount, ThrottleStatus, resource_amount_of_pod
 
-    here = os.path.dirname(os.path.abspath(__file__))
-    with open(os.path.join(here, "data", f"{cfg}.json")) as f:
+    with open(rehearsal.tiny_config(cfg)) as f:
         config = json.load(f)
     config.update(pods=min(config["pods"], 400))
-    state = importlib.import_module(gen).generate(config, {"pending_pods": 50}, 2**31 + 7)
+    gen = importlib.import_module(config["generator"])
+    state = gen.generate(config, {"pending_pods": 50}, 2**31 + 7)
     ref = cluster(state["namespaces"], state["throttles"], state["pods"])
     nss = {n["metadata"]["name"]: object_from_dict(n) for n in state["namespaces"]}
     pods = [object_from_dict(p) for p in state["pods"]]

@@ -1,7 +1,8 @@
-"""Every cell end to end on the CPU at a tiny size, from a checkout that
-holds the tiny configuration and traffic files and an added metric reader
-(``rehearsal.make_root``); then the same runs with the timed path broken
-underneath, and the control, each of which must come out not correct."""
+"""Every cell of ``BENCHMARK.json`` end to end on the CPU at a tiny size,
+from a checkout that holds the tiny configuration and traffic files and an
+added metric reader (``rehearsal.make_root``); then the same runs with the
+timed path broken underneath by each fault its routes declare, and the
+control, each of which must come out not correct."""
 
 import json
 import os
@@ -13,7 +14,8 @@ import pytest
 import control
 import rehearsal
 
-CELLS = ["large.cycle", "large.triage", "tenant.triage", "large.tick"]
+CONFIGS = [c["name"] for c in rehearsal.benchmark()["configs"]]
+CELLS = [c["name"] for c in rehearsal.cells()]
 
 
 @pytest.fixture(scope="module")
@@ -56,20 +58,14 @@ def test_traced_run_reads_every_per_layer_metric(root, cell):
     got = set(result["metrics"])
     # the metric that exists only as an added file is found by name
     assert "dummy_calls" in got
-    # at this size triage is answered by the host dedupe and the cycle's
-    # scans never reach the device: the device readers may stay silent there
-    assert got <= want and want - got <= {"device_idle.prefilter", "device_idle.triage",
-                                          "kernel_ms.triage", "plugin_ms.triage"}
+    # what the tiny configuration says may stay silent at its size, as the
+    # device readers do where the host dedupe answers triage
+    assert got <= want and want - got <= rehearsal.silent_at_tiny(cell)
     assert result["correct"] and "busy_s" in result["device"]
     assert set(result["breakdown"]) == {"device_ops", "idle_gaps"}
 
 
-@pytest.mark.parametrize("cell,fault", [
-    ("large.cycle", "altered_answer"), ("large.cycle", "state_unchanged"),
-    ("large.triage", "altered_answer"), ("large.triage", "half_batch"),
-    ("tenant.triage", "half_batch"), ("large.tick", "altered_answer"),
-    ("large.tick", "half_batch"),
-])
+@pytest.mark.parametrize("cell,fault", [(c, f) for c in CELLS for f in rehearsal.faults(c)])
 def test_broken_timed_path_is_not_correct(root, cell, fault):
     result, _ = run_cell(root, cell, fault=fault)
     assert result["correct"] is False
@@ -79,8 +75,20 @@ def test_broken_timed_path_is_not_correct(root, cell, fault):
 @pytest.mark.parametrize("cell", CELLS)
 @pytest.mark.parametrize("seed", [3, 2**31 + 3, 2**33 + 1])
 def test_control_is_not_correct(root, cell, seed):
-    out = control.compared(cell, seed, root=root)
-    assert any(v["value"] > v["limit"] for v in out.values())
+    # each route of the mix on its own numbers: the cycle's PreFilter check
+    # must catch the flip whatever the scan's triage check reads
+    by_route = control.compared(cell, seed, root=root)
+    assert by_route and control.fails(by_route), by_route
+
+
+@pytest.mark.parametrize("config", CONFIGS)
+def test_every_configuration_has_a_tiny_size(config):
+    path = rehearsal.tiny_config(config)
+    left_out = [c["name"] for c in rehearsal.benchmark()["workloads"] if c["config"] == config]
+    assert os.path.isfile(path), f"no tiny size at {path}: cells {left_out} are not rehearsed"
+    entry = next(c for c in rehearsal.benchmark()["configs"] if c["name"] == config)
+    with open(os.path.join(rehearsal.REPO, entry["file"])) as f, open(path) as g:
+        assert json.load(f)["generator"] == json.load(g)["generator"]
 
 
 def test_no_result_without_the_program(tmp_path):
@@ -88,7 +96,8 @@ def test_no_result_without_the_program(tmp_path):
 
     shutil.copytree(rehearsal.BENCH, tmp_path / "benchmark")
     shutil.copy(os.path.join(rehearsal.REPO, "BENCHMARK.json"), tmp_path)
+    cell = rehearsal.benchmark()["workloads"][0]["name"]
     proc = subprocess.run(
-        [sys.executable, "benchmark/run.py", "--workload", "large.tick", "--seed", "1",
+        [sys.executable, "benchmark/run.py", "--workload", cell, "--seed", "1",
          "--seconds", "1", "--trace", "0"], cwd=tmp_path, capture_output=True, text=True)
     assert proc.returncode != 0 and not proc.stdout.strip()
