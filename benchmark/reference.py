@@ -19,14 +19,26 @@ everpeace/kube-throttler's Go sources as published:
   insufficient (``used + reserved + request``), not-throttled. A request
   flag only blocks a pod that requests that resource non-zero.
 
+- a spec's ``temporaryThresholdOverrides`` resolve at an instant as
+  ``CalculateThreshold`` does (throttle_types.go:65-106): an override is
+  active where ``begin <= now <= end``, both ends inclusive, an empty
+  string an open end; one whose RFC3339 string does not parse is skipped;
+  the first active override wins for the pod count and for each resource
+  apart; if any is active, the merge replaces the whole spec threshold.
+
 Amounts are ``(count, requests)``: ``count`` is None where Go's pointer is
 nil, ``requests`` maps resource names to exact quantities in nano-units: an
-int, or a ``Fraction`` for the rare quantity finer than that.
+int, or a ``Fraction`` for the rare quantity finer than that. Instants are
+whole nanoseconds since the Unix epoch.
 """
 
+import bisect
 import json
+import re
+import time
+from datetime import date
 from fractions import Fraction
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Iterable, List, Optional, Tuple
 
 NOT_THROTTLED = "not-throttled"
 ACTIVE = "active"
@@ -40,6 +52,15 @@ RANK = {NOT_THROTTLED: 0, INSUFFICIENT: 1, ACTIVE: 2}
 # of one commit by the program's design. A reading may count a bind twice
 # for this long after it, and no older one
 RECONCILE_S = 5.0
+
+# an answer's instant is read on the daemon's clock, its call's interval on
+# the harness's mapping of perf_counter to wall time: an instant within this
+# of the interval counts as inside it
+CLOCK_SLACK_NS = 1_000_000
+
+
+class RunError(Exception):
+    """The run cannot produce a result (no result line is printed)."""
 
 NANO = 10**9
 _BINARY = {"Ki": 2**10, "Mi": 2**20, "Gi": 2**30, "Ti": 2**40, "Pi": 2**50, "Ei": 2**60}
@@ -104,6 +125,69 @@ def add(a, b):
 
 
 ZERO = (None, {})
+
+# time.RFC3339 as Go's parser reads it: fractional seconds optional, any
+# number of digits, those past the ninth dropped
+_RFC3339 = re.compile(r"(\d{4})-(\d{2})-(\d{2})T(\d{2}):(\d{2}):(\d{2})(?:\.(\d+))?"
+                      r"(Z|([+-])(\d{2}):(\d{2}))")
+_EPOCH_DAY = date(1970, 1, 1).toordinal()
+
+
+def instant_ns(s: str) -> Optional[int]:
+    """An RFC3339 time in ns since the epoch, exactly; None where it does
+    not parse."""
+    m = _RFC3339.fullmatch(s)
+    if m is None:
+        return None
+    year, month, day, hour, minute, sec = (int(g) for g in m.group(1, 2, 3, 4, 5, 6))
+    if hour > 23 or minute > 59 or sec > 59:
+        return None
+    try:
+        days = date(year, month, day).toordinal() - _EPOCH_DAY
+    except ValueError:
+        return None
+    offset = 0
+    if m.group(8) != "Z":
+        off_h, off_m = int(m.group(10)), int(m.group(11))
+        if off_h > 23 or off_m > 59:
+            return None
+        offset = (off_h * 3600 + off_m * 60) * (1 if m.group(9) == "+" else -1)
+    frac = int((m.group(7) or "")[:9].ljust(9, "0"))
+    return (days * 86400 + hour * 3600 + minute * 60 + sec - offset) * NANO + frac
+
+
+def overrides_of(spec: dict) -> list:
+    """``[(begin, end, threshold)]`` of a spec's overrides that parse, in
+    order; an open end is None."""
+    out = []
+    for o in spec.get("temporaryThresholdOverrides") or []:
+        ends = [o.get("begin") or "", o.get("end") or ""]
+        at = [None if e == "" else instant_ns(e) for e in ends]
+        if all(e == "" or t is not None for e, t in zip(ends, at)):
+            out.append((at[0], at[1], amount_of(o.get("threshold"))))
+    return out
+
+
+def threshold_at(spec_threshold, overrides: list, t_ns: int):
+    """The threshold in force at instant ``t_ns``: the first active
+    override per dimension, replacing the whole spec threshold if any is
+    active."""
+    count, requests, active = None, {}, False
+    for begin, end, (o_count, o_requests) in overrides:
+        if (begin is None or begin <= t_ns) and (end is None or t_ns <= end):
+            active = True
+            if count is None:
+                count = o_count
+            for k, v in (o_requests or {}).items():
+                requests.setdefault(k, v)
+    return (count, requests) if active else spec_threshold
+
+
+def boundaries(overrides: list) -> List[int]:
+    """The instants at which the set of active overrides may change, sorted:
+    every begin, and every end + 1 ns."""
+    return sorted({b for b, _, _ in overrides if b is not None}
+                  | {e + 1 for _, e, _ in overrides if e is not None})
 
 
 def throttled_flags(threshold, used, on_equal: bool):
@@ -190,7 +274,16 @@ def _candidates(selector: dict, ns: str, by_label, by_ns) -> List[str]:
 class Cluster:
     """Namespaces, throttles of both kinds and pods, as manifests, with the
     statuses a reconcile derives from them. ``throttler`` and ``scheduler``
-    are the names the daemon governs."""
+    are the names the daemon governs.
+
+    ``thresholds`` holds each throttle's threshold at the instant last
+    passed to ``resolve`` (at construction, the clock's); ``bounds`` the
+    sorted boundaries of each throttle whose threshold changes with time.
+    Whoever judges a window sets ``wall_ns`` (perf_counter seconds to an
+    instant) and ``window`` (its start and end in perf_counter seconds)."""
+
+    wall_ns = None
+    window = None
 
     def __init__(self, namespaces: List[dict], throttles: List[dict], pods: List[dict],
                  throttler: str, scheduler: str):
@@ -201,8 +294,22 @@ class Cluster:
                           if t["spec"].get("throttlerName") == throttler}
         self.thresholds = {k: amount_of(t["spec"].get("threshold"))
                            for k, t in self.throttles.items()}
+        # throttle key -> boundaries, and the threshold before the first and
+        # from each on, for the throttles with overrides
+        self.bounds: Dict[str, List[int]] = {}
+        self._segments: Dict[str, list] = {}
+        for k, t in self.throttles.items():
+            if t["spec"].get("temporaryThresholdOverrides"):
+                ovs = overrides_of(t["spec"])
+                bs = boundaries(ovs)
+                if bs:
+                    self.bounds[k] = bs
+                self._segments[k] = [threshold_at(self.thresholds[k], ovs, x)
+                                     for x in [bs[0] - 1 if bs else 0] + bs]
+        self.resolve(time.time_ns())
         self.pods = {pod_key(p): p for p in pods}
         self.requests = {k: pod_requests(p) for k, p in self.pods.items()}
+        self._sigs: Dict[str, tuple] = {}
         # (namespace, label key, value) -> pod keys: a term only looks at
         # the pods that carry one of the labels it requires
         by_label: Dict[Tuple[str, str, str], List[str]] = {}
@@ -243,6 +350,46 @@ class Cluster:
             if sig not in usage_memo:
                 usage_memo[sig] = self.usage(tkey)
             self.used[tkey] = usage_memo[sig]
+
+    def threshold_at(self, tkey: str, t_ns: int):
+        segments = self._segments.get(tkey)
+        if segments is None:
+            return self.thresholds[tkey]
+        return segments[bisect.bisect_right(self.bounds.get(tkey, ()), t_ns)]
+
+    def resolve(self, t_ns: int, keys: Optional[Iterable[str]] = None) -> None:
+        """Sets the thresholds of ``keys`` (default: every throttle with
+        overrides) to those at instant ``t_ns``."""
+        for k in self._segments if keys is None else keys:
+            self.thresholds[k] = self.threshold_at(k, t_ns)
+
+    def crossing(self, a: int, b: int) -> Dict[str, List[int]]:
+        """{throttle key: its boundaries x with a < x <= b}: where its
+        threshold may change between instants a and b."""
+        out = {}
+        for k, bs in self.bounds.items():
+            lo, hi = bisect.bisect_right(bs, a), bisect.bisect_right(bs, b)
+            if lo < hi:
+                out[k] = bs[lo:hi]
+        return out
+
+    def hold_still(self, route: str) -> None:
+        """For a check of written statuses: resolves the thresholds at the
+        window's start, and raises ``RunError`` where a boundary lies in
+        [start - ``RECONCILE_S``, end]. A written status lags a boundary by
+        the reconcile queue's delay, which no bound covers yet."""
+        if not self.bounds:
+            return
+        start, end = (self.wall_ns(t) for t in self.window)
+        moving = self.crossing(start - int(RECONCILE_S * NANO) - 1, end)
+        if moving:
+            k = min(moving, key=lambda k: moving[k][0])
+            raise RunError(f"{route}: the threshold of {k} changes at {moving[k][0]} ns, within "
+                           f"{RECONCILE_S:g} s before or inside the window, and {len(moving)} "
+                           "throttles' in all; written statuses lag such a change by the "
+                           "reconcile queue, which no bound covers: judge this state on the "
+                           "tick route")
+        self.resolve(start)
 
     def counted(self, pod: dict) -> bool:
         spec, status = pod.get("spec", {}), pod.get("status") or {}
@@ -291,14 +438,18 @@ class Cluster:
                 out[tkey] = st
         return out
 
-    def schedulable(self, extra: Optional[Dict[str, tuple]] = None) -> Dict[str, bool]:
-        """Every pod's verdict (``extra`` as for ``verdict``); pods that share
-        matched throttles and requests share it, so it is computed once per
-        such pair."""
+    def schedulable(self, extra: Optional[Dict[str, tuple]] = None,
+                    pods: Optional[Iterable[str]] = None) -> Dict[str, bool]:
+        """The verdict of every pod, or of ``pods`` (``extra`` as for
+        ``verdict``); pods that share matched throttles and requests share
+        it, so it is computed once per such pair."""
         memo: Dict[Tuple, bool] = {}
         out = {}
-        for pk in self.pods:
-            sig = (tuple(self.matching[pk]), tuple(sorted(self.requests[pk].items())))
+        for pk in self.pods if pods is None else pods:
+            sig = self._sigs.get(pk)
+            if sig is None:
+                sig = self._sigs[pk] = (tuple(self.matching[pk]),
+                                        tuple(sorted(self.requests[pk].items())))
             ok = memo.get(sig)
             if ok is None:
                 ok = memo[sig] = not self.verdict(pk, extra)

@@ -5,7 +5,10 @@ Records ``prefilter``, ``reserve`` and ``bind`` calls.
 The check: every PreFilter of the window against the reference after the
 binds the window made before it. A bound pod counts once, as reserved or as
 used; within ``reference.RECONCILE_S`` of its bind it may count twice, so a
-state may lie between the two (never below the first)."""
+state may lie between the two (never below the first). PreFilter reads
+written statuses, so a state whose thresholds change near or inside the
+window is refused (``reference.Cluster.hold_still``); any other is judged
+at the window's start."""
 
 import collections
 import json
@@ -54,6 +57,7 @@ def served_map(resp: dict) -> dict:
 
 
 def check(records, ref, say) -> dict:
+    ref.hold_still("cycle")
     recent = collections.defaultdict(collections.deque)  # throttle -> (t, amount)
     wrong, above, checked = 0, 0, 0
     for route, t0, t1, ok, payload in records:

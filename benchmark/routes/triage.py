@@ -9,7 +9,10 @@ grows. So each pod's verdict has to lie between the reference with the
 binds finished before the call was sent (each counted once) and with every
 pod reserved before it returned, plus every bind of the last
 ``reference.RECONCILE_S`` counted twice. A pod on which the two agree, as
-every pod does in a window that binds nothing, is checked exactly."""
+every pod does in a window that binds nothing, is checked exactly. The
+answers read written statuses, so a state whose thresholds change near or
+inside the window is refused (``reference.Cluster.hold_still``); any other
+is judged at the window's start."""
 
 import json
 
@@ -59,6 +62,7 @@ def bounds(ref, once, later, twice):
 
 
 def check(records, ref, say) -> dict:
+    ref.hold_still("triage")
     wrong, undecided, answers = 0, 0, 0
     states, judged = {}, {}
     for route, t0, t1, ok, raw in records:

@@ -42,12 +42,9 @@ ROOT = os.path.dirname(BENCH)
 sys.path[:0] = [BENCH, ROOT]
 import devtrace  # noqa: E402
 import reference  # noqa: E402
+from reference import RunError  # noqa: E402
 
 READY_TIMEOUT_S = 1000.0
-
-
-class RunError(Exception):
-    """The run cannot produce a result (no result line is printed)."""
 
 
 def say(msg: str) -> None:
@@ -278,13 +275,14 @@ def warm(d: Daemon, route, stream: dict, state: dict) -> int:
 
 def check_all(records, ref: reference.Cluster, routes: dict) -> dict:
     """Each route's check of the window's records, each from the state at
-    the window's start. Returns {name: (value, limit)}."""
-    base = dict(ref.used)
+    the window's start (``ref.wall_ns`` and ``ref.window`` set). Returns
+    {name: (value, limit)}."""
+    base, thresholds = dict(ref.used), dict(ref.thresholds)
     compared = {}
     for name in sorted(routes):
-        ref.used = dict(base)
+        ref.used, ref.thresholds = dict(base), dict(thresholds)
         compared.update(routes[name].check(records, ref, say))
-    ref.used = base
+    ref.used, ref.thresholds = base, thresholds
     return compared
 
 
@@ -478,7 +476,10 @@ def run(args) -> int:
         attempted = len(records)
         failed = sum(not r[3] for r in records)
         compared = {"failed_calls": (failed, 0)}
+        ref.wall_ns, ref.window = w.wall_ns, (start, end)
+        t_check = time.perf_counter()
         compared.update(check_all(records, ref, routes))
+        say(f"check: {time.perf_counter() - t_check:.1f}s to judge the window")
         correct = attempted > 0 and all(v <= lim for v, lim in compared.values())
         for route in sorted(n_calls):
             lat = sorted(runinfo.latencies(route))

@@ -13,13 +13,16 @@ another's. Each stream of the mix is answered by its route's
 ``control(ctl, stream, state, calls)``, which returns the records the
 window would hold with the reference ``ctl`` in the program's place. The
 cycle's is driven as the window drives it: the control's own answers
-decide which pods are bound.
+decide which pods are bound. The records' times map to the wall clock from
+the moment the control starts, for the control's answers and the check
+alike.
 """
 
 import contextlib
 import json
 import os
 import sys
+import time
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path[:0] = [BENCH]
@@ -37,15 +40,18 @@ def on_equal_flipped():
         reference.throttled_flags = flags
 
 
-def control_records(state: dict, cfg: dict, traffic: dict, routes: dict, calls: int) -> list:
+def control_records(state: dict, cfg: dict, traffic: dict, routes: dict, calls: int,
+                    wall_ns) -> list:
     """The window's records, each stream answered by its route's
-    ``control`` from a flipped reference of its own over the starting state."""
+    ``control`` from a flipped reference of its own over the starting
+    state, on the clock ``wall_ns``."""
     records = []
     with on_equal_flipped():
         for stream in traffic["streams"]:
             own = json.loads(json.dumps(state))
             ctl = reference.Cluster(own["namespaces"], own["throttles"], own["pods"],
                                     cfg["throttler_name"], cfg["scheduler_name"])
+            ctl.wall_ns = wall_ns
             records += routes[stream["route"]].control(ctl, stream, own, calls)
     return sorted(records, key=lambda r: r[1])
 
@@ -68,9 +74,15 @@ def compared(cell: str, seed: int, root: str = os.path.dirname(BENCH), calls: in
     routes = {s["route"]: harness.load_module(
         os.path.join(root, "benchmark", "routes", f"{s['route']}.py"), f"route_{s['route']}")
         for s in traffic["streams"]}
-    records = control_records(state, cfg, traffic, routes, calls)
+    wall0 = time.time_ns()
+
+    def wall_ns(t: float) -> int:
+        return wall0 + int(t * 1e9)
+
+    records = control_records(state, cfg, traffic, routes, calls, wall_ns)
     ref = reference.Cluster(state["namespaces"], state["throttles"], state["pods"],
                             cfg["throttler_name"], cfg["scheduler_name"])
+    ref.wall_ns, ref.window = wall_ns, (0.0, max(r[2] for r in records))
     return {name: {k: {"value": v, "limit": lim}
                    for k, (v, lim) in harness.check_all(records, ref, {name: mod}).items()}
             for name, mod in routes.items()}

@@ -6,7 +6,10 @@ nothing else does.
   opposite, and one pod's verdict is flipped in every triage and tick answer;
 - ``half_batch``: triage and tick answers leave out every other pod;
 - ``state_unchanged``: Reserve and /v1/bind acknowledge and change
-  nothing, so the state the next PreFilter reads has not moved.
+  nothing, so the state the next PreFilter reads has not moved;
+- ``overrides_ignored``: the tick encodes every throttle's spec without its
+  ``temporaryThresholdOverrides``, so it resolves every threshold from the
+  spec alone.
 """
 
 
@@ -60,5 +63,17 @@ def install(name: str) -> None:
 
         ThrottlerHTTPServer._post = no_bind
         KubeThrottler.reserve = lambda self, pod, node="": Status(StatusCode.SUCCESS)
+    elif name == "overrides_ignored":
+        import dataclasses
+
+        from kube_throttler_tpu.ops import overrides
+
+        encode = overrides.encode_override_schedule
+
+        def spec_alone(specs, *args, **kwargs):
+            return encode([s and dataclasses.replace(s, temporary_threshold_overrides=())
+                           for s in specs], *args, **kwargs)
+
+        overrides.encode_override_schedule = spec_alone
     else:
         raise ValueError(f"unknown fault {name!r}")

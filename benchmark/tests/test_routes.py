@@ -20,6 +20,10 @@ def route(name):
 
 @pytest.fixture
 def window():
+    return binds_window()
+
+
+def binds_window():
     """Throttle t (cpu 1) holds 600m; pending a (300m) and b (200m): after
     a's bind neither a nor b fits (a pod's own request counts again), and
     the unthrottled c fits throughout."""
@@ -70,3 +74,80 @@ def test_static_window_is_exact(window):
     assert triage_wrong(ref, [("triage", 1.0, 2.0, True, answer())]) == 0
     assert triage_wrong(ref, [("triage", 1.0, 2.0, True, answer(b=False))]) == 1
     assert R.RECONCILE_S > 0
+
+
+W0 = R.instant_ns("2026-03-01T00:00:00Z")
+
+
+def on_clock(ref, start, end):
+    """The window [start, end] in seconds from W0 on the wall clock."""
+    ref.wall_ns, ref.window = (lambda t: W0 + int(t * 1e9)), (start, end)
+    return ref
+
+
+@pytest.fixture
+def calendar():
+    """The window's state, with an override of t to cpu 800m from 5 s to
+    15 s: a (300m) fits under the spec's cpu 1 and not under the override."""
+    ref, _ = binds_window()
+    thr = ref.throttles["default/t"]
+    thr["spec"]["temporaryThresholdOverrides"] = [
+        {"begin": "2026-03-01T00:00:05Z", "end": "2026-03-01T00:00:15Z",
+         "threshold": {"resourceRequests": {"cpu": "800m"}}}]
+    return cluster([NS], list(ref.throttles.values()), list(ref.pods.values()))
+
+
+def tick(t0, t1, **sched):
+    used = {"throttle": {"default/t": 1}, "clusterthrottle": {}}
+    return ("tick", t0, t1, True, json.dumps(dict(json.loads(answer(**sched)), used=used)).encode())
+
+
+def tick_check(ref, records):
+    lines = []
+    out = route("tick").check(records, on_clock(ref, 0.0, 30.0), lines.append)
+    return out["tick_wrong"][0], lines[-1]
+
+
+def test_tick_judges_each_answer_at_its_instant(calendar):
+    assert calendar.bounds["default/t"] == [W0 + 5 * R.NANO, W0 + 15 * R.NANO + 1]
+    # before the override, on it, and after it: each exactly
+    assert tick_check(calendar, [tick(1.0, 2.0), tick(6.0, 7.0, a=False),
+                                 tick(15.5, 16.5)])[0] == 0
+    assert tick_check(calendar, [tick(1.0, 2.0, a=False)])[0] == 1
+    # an answer that ignores the override is wrong while it holds
+    assert tick_check(calendar, [tick(6.0, 7.0)])[0] == 1
+    assert tick_check(calendar, [tick(14.0, 15.0 - 0.002)])[0] == 1
+    # a call across a boundary may answer either side of it, and says so
+    for t0, t1 in ((4.5, 5.5), (14.5, 15.5), (4.9995, 5.0)):
+        for v in (True, False):
+            wrong, line = tick_check(calendar, [tick(t0, t1, a=v)])
+            assert wrong == 0 and "1 pod verdicts left open" in line, (t0, t1, v)
+    # the pods the boundary does not touch stay exact
+    assert tick_check(calendar, [tick(4.5, 5.5, c=False)])[0] == 1
+
+
+def test_tick_without_overrides_needs_no_clock(window):
+    ref, _ = window
+    lines = []
+    out = route("tick").check([tick(1.0, 2.0), tick(3.0, 4.0, b=False)], ref, lines.append)
+    assert out["tick_wrong"] == (1, 0) and out["tick_used_wrong"] == (0, 0)
+    assert "0 pod verdicts left open" in lines[-1]
+
+
+@pytest.mark.parametrize("name", ["triage", "cycle"])
+def test_status_routes_refuse_a_boundary_near_the_window(calendar, name):
+    # 5 s lies inside the window, and 15 s + 1 ns within RECONCILE_S before it
+    for start, end in ((0.0, 10.0), (19.0, 30.0)):
+        with pytest.raises(R.RunError, match=f"^{name}: "):
+            route(name).check([], on_clock(calendar, start, end), lambda _: None)
+
+
+def test_status_routes_judge_at_the_window_start(calendar):
+    """Clear of every boundary, triage judges at the window's start: a does
+    not fit while the override holds, and fits after it."""
+    on_clock(calendar, 10.5, 14.0)
+    assert triage_wrong(calendar, [("triage", 11.0, 12.0, True, answer(a=False))]) == 0
+    assert triage_wrong(calendar, [("triage", 11.0, 12.0, True, answer())]) == 1
+    on_clock(calendar, 21.0, 30.0)
+    assert triage_wrong(calendar, [("triage", 22.0, 23.0, True, answer())]) == 0
+    assert route("cycle").check([], calendar, lambda _: None) == {"prefilter_wrong": (0, 0)}
