@@ -27,8 +27,9 @@ output forms:
 - ``check_pods_compact``  → int32[P,4] per-pod class counts + bool[P]
   schedulable (dense batch form: 100k×10k never materializes [P,T]);
 - ``check_pods_gather``   → same outputs from int32[P,K] matched-cols lists
-  instead of a mask: computes P×K×R, the batch path the device manager
-  dispatches in production (real masks are sparse — K ≪ T).
+  instead of a mask: one row gather of a packed per-throttle record per
+  slot, the batch path the device manager dispatches in production (real
+  masks are sparse — K ≪ T).
 
 The two static booleans (kind asymmetry, caller onEqual) select among 4
 compiled variants; shapes are padded so object churn never recompiles.
@@ -69,25 +70,21 @@ def _classify_core(
     thr_cnt, thr_cnt_present, thr_req, thr_req_present,
     st_cnt_throttled, st_req_flag_present, st_req_throttled,
     au_cnt, au_cnt_present, au_req, au_req_present,
-    on_equal: bool, step3_on_equal: bool, axis: int = -1,
+    on_equal: bool, step3_on_equal: bool,
 ):
-    """The 4-step ordered resolution on broadcast-compatible operands:
-    pod side [P,1(,R)], throttle side [1,T(,R)] (dense) or R-leading
-    [R,P,1] / [R,P,K] (gather — see _gather_statuses for why). ``axis``
-    names the R dimension of the per-resource operands; the count-side
-    operands never carry it. One body ⇒ the dense and sparse kernels
-    cannot drift."""
+    """The 4-step check on broadcast-compatible operands: pod side
+    [P,1(,R)], throttle side [1,T(,R)], reduced over the trailing R."""
     # --- step 1: pod alone vs threshold (onEqual=False) -------------------
     # pod count is always 1 and always present
     exceeds_cnt = thr_cnt_present & (1 > thr_cnt)
     exceeds_req = jnp.any(
-        thr_req_present & pod_present & (pod_req > thr_req) & (pod_req != 0), axis=axis
+        thr_req_present & pod_present & (pod_req > thr_req) & (pod_req != 0), axis=-1
     )
     exceeds = exceeds_cnt | exceeds_req
 
     # --- step 2: persisted throttled flags --------------------------------
     st_active = st_cnt_throttled | jnp.any(
-        st_req_flag_present & st_req_throttled & pod_nonzero, axis=axis
+        st_req_flag_present & st_req_throttled & pod_nonzero, axis=-1
     )
 
     # --- step 3: used + reserved saturation -------------------------------
@@ -97,7 +94,7 @@ def _classify_core(
         & au_req_present
         & _cmp(au_req, thr_req, step3_on_equal)
         & pod_nonzero,
-        axis=axis,
+        axis=-1,
     )
     saturated = sat_cnt | sat_req
 
@@ -113,16 +110,19 @@ def _classify_core(
         & tot_req_present
         & _cmp(tot_req, thr_req, on_equal)
         & pod_nonzero,
-        axis=axis,
+        axis=-1,
     )
     insufficient = over_cnt | over_req
+    return _resolve(exceeds, st_active | saturated, insufficient)
 
-    # --- ordered resolution ----------------------------------------------
+
+def _resolve(exceeds, active, insufficient):
+    """The ordered resolution, shared by the dense and sparse kernels."""
     return jnp.where(
         exceeds,
         jnp.int8(CHECK_POD_EXCEEDS),
         jnp.where(
-            st_active | saturated,
+            active,
             jnp.int8(CHECK_ACTIVE),
             jnp.where(insufficient, jnp.int8(CHECK_INSUFFICIENT), jnp.int8(CHECK_NOT_THROTTLED)),
         ),
@@ -201,11 +201,12 @@ def check_step(state: ThrottleState, pods: PodBatch, mask: jnp.ndarray):
 def check_pods_gather(state: ThrottleState, pods: PodBatch, cols: jnp.ndarray,
                       on_equal: bool = False, step3_on_equal: bool = True):
     """Sparse batch check: ``cols`` int32[P,K] lists each pod's matched
-    throttle columns (-1 pads empty slots). Gathers the K throttle rows per
-    pod and runs the same 4-step resolution as ``check_pods_compact`` over
-    [P,K,R] instead of [P,T,R] — on real clusters each pod matches a
-    handful of throttles, so K ≪ T and the batch drops ~T/K× in both FLOPs
-    and memory traffic (and needs no [P,T] mask on device at all).
+    throttle columns (-1 pads empty slots). Gathers the K throttle records
+    per pod and runs the same 4-step resolution as ``check_pods_compact``
+    over [P,K] slots instead of [P,T] cells — on real clusters each pod
+    matches a handful of throttles, so K ≪ T and the batch drops ~T/K× in
+    both FLOPs and memory traffic (and needs no [P,T] mask on device at
+    all).
 
     Returns ``(counts int32[P,4], schedulable bool[P])``, identical to
     ``check_pods_compact`` given a cols/mask pair describing the same
@@ -225,50 +226,126 @@ def check_pods_gather(state: ThrottleState, pods: PodBatch, cols: jnp.ndarray,
     )
 
 
+# Flag bits of a gathered record's first word.
+_F_VALID, _F_EXCEEDS_CNT, _F_ACTIVE_CNT, _F_OVER_CNT = 1, 2, 4, 8
+_INT64_MAX = jnp.iinfo(jnp.int64).max
+_INT64_MIN = jnp.iinfo(jnp.int64).min
+
+
+def _bitmask(bits):
+    """bool[N,R] → int32[N, ceil(R/32)], bit r%32 of word r//32."""
+    n, r = bits.shape
+    nw = -(-r // 32)
+    b = jnp.pad(bits, ((0, 0), (0, nw * 32 - r))).reshape(n, nw, 32).astype(jnp.uint32)
+    words = jnp.sum(b << jnp.arange(32, dtype=jnp.uint32), axis=-1, dtype=jnp.uint32)
+    return lax.bitcast_convert_type(words, jnp.int32)
+
+
+def _gather_table(state, on_equal, step3_on_equal):
+    """The pod-independent record of each throttle, int32[T, 1 + nw + 4R]
+    (nw = ceil(R/32)), in the residual form of ``fastcheck``:
+
+    - word 0, flags: valid, step-1 count exceeds, step-2/3 count active
+      (``step3_on_equal`` variant), step-4 count over (``on_equal``);
+    - nw words, per dim: step-2 flag or step-3 saturation;
+    - per dim, the step-1 target ``thr_req`` and the step-4 target
+      ``thr − (used + reserved)`` (less 1 under ``on_equal``, so both
+      compares are strict; exact, as the residual cannot be int64 min
+      for any state ``fastcheck``'s overflow note admits), each as (hi,
+      lo) int32 limbs, hi and lo planes of R words each. An absent
+      threshold dim holds int64 max, which no pod request exceeds.
+    """
+    from .fastcheck import precompute_check_state
+    from .pallas_check import _split_limbs
+
+    pre = precompute_check_state(state)
+    sat_cnt = pre.sat_cnt_ge if step3_on_equal else pre.sat_cnt_gt
+    sat_req = pre.sat_req_ge if step3_on_equal else pre.sat_req_gt
+    over_cnt = pre.over_cnt_ge if on_equal else pre.over_cnt_gt
+    flags = sum(
+        b.astype(jnp.int32) * bit
+        for b, bit in ((pre.valid, _F_VALID), (pre.exceeds_cnt, _F_EXCEEDS_CNT),
+                       (pre.st_cnt | sat_cnt, _F_ACTIVE_CNT), (over_cnt, _F_OVER_CNT))
+    )
+    resid = pre.resid - 1 if on_equal else pre.resid
+    thr_hi, thr_lo = _split_limbs(jnp.where(pre.thr_req_present, pre.thr_req, _INT64_MAX))
+    res_hi, res_lo = _split_limbs(jnp.where(pre.thr_req_present, resid, _INT64_MAX))
+    return jnp.concatenate(
+        [flags[:, None], _bitmask(pre.st_req | sat_req), thr_hi, thr_lo, res_hi, res_lo],
+        axis=1,
+    )
+
+
+def _pod_rows(pods):
+    """The pod side of the sparse check: (valid bool[P], request limbs
+    (hi, lo) int32[P,R] each, nonzero-dims bitmask int32[P,nw]). A dim the
+    pod does not request non-zero holds int64 min, which exceeds nothing."""
+    from .pallas_check import _split_limbs
+
+    nonzero = pods.req_present & (pods.req != 0)
+    hi, lo = _split_limbs(jnp.where(nonzero, pods.req, _INT64_MIN))
+    return pods.valid, hi, lo, _bitmask(nonzero)
+
+
+def _classify_slots(table, valid, hi, lo, bits, cols):
+    """int8[P,K] statuses of pods (valid, hi, lo, bits) against the table
+    rows their cols name: one row gather per slot, then compares of the
+    pod's own limbs against the gathered targets. Flag and bitmask tests
+    are lane-masked reductions over the whole record, so no one-lane
+    slice of it (a 128-lane tile per word) is materialized."""
+    from .pallas_check import _limb_gt
+
+    P, K = cols.shape
+    R, W = hi.shape[1], table.shape[1]
+    o = 1 + bits.shape[1]
+    g = jnp.take(table, jnp.maximum(cols, 0).reshape(-1), axis=0, mode="clip")
+    g = g.reshape(P, K, W)
+
+    def shares_bits(row):  # row int32[P or 1, ≤W], zero-extended to W lanes
+        row = jnp.pad(row, ((0, 0), (0, W - row.shape[1])))
+        return jnp.any((g & row[:, None, :]) != 0, axis=-1)
+
+    def flag(bit):
+        return shares_bits(jnp.full((1, 1), bit, dtype=jnp.int32))
+
+    def limb_any(i):  # pod > the i-th target block, in any dim
+        return jnp.any(_limb_gt(hi[:, None, :], lo[:, None, :],
+                                g[:, :, o + 2 * i * R:o + (2 * i + 1) * R],
+                                g[:, :, o + (2 * i + 1) * R:o + (2 * i + 2) * R]), axis=-1)
+
+    active_row = jnp.concatenate(
+        [jnp.full((P, 1), _F_ACTIVE_CNT, dtype=jnp.int32), bits], axis=1
+    )
+    exceeds = flag(_F_EXCEEDS_CNT) | limb_any(0)
+    active = shares_bits(active_row)
+    insufficient = flag(_F_OVER_CNT) | limb_any(1)
+    slot = (cols >= 0) & flag(_F_VALID) & valid[:, None]
+    return jnp.where(
+        slot, _resolve(exceeds, active, insufficient), jnp.int8(CHECK_NOT_AFFECTED)
+    )
+
+
 def _gather_statuses(state, pods, cols, on_equal, step3_on_equal):
     """Shared body of the sparse gather kernels: int8[P,K] per-slot
     statuses (CHECK_NOT_AFFECTED for padded/invalid slots).
 
-    Orientation: the per-resource operands are gathered R-LEADING —
-    ``state.thr_req.T[:, c]`` → [R,P,K] — not the naive ``thr_req[c]`` →
-    [P,K,R]. TPU tiles the two minor dims (8,128): an R-minor gather
-    result pads R=8 → 128 lanes, a 16× memory/bandwidth expansion that
-    OOM'd the 100k×10k prewarm on a 16G v5e (4G per gathered u32 operand,
-    observed r5). R-leading puts K on the lane dim (pads ≤2× at K=64 and
-    not at all from 128 up) and R on the cheap outer dim; the reduction
-    over R becomes ``axis=0``."""
-    c = jnp.maximum(cols, 0)  # [P,K]; padded slots gather col 0 then mask out
-    slot = (cols >= 0) & state.valid[c] & pods.valid[:, None]
-
-    def g(a):  # [T,R] per-resource state → [R,P,K]
-        return a.T[:, c]
-
-    pod_req = pods.req.T[:, :, None]  # [R,P,1]
-    pod_present = pods.req_present.T[:, :, None]
-    pod_nonzero = pod_present & (pod_req != 0)
-
-    result = _classify_core(
-        pod_req, pod_present, pod_nonzero,
-        state.thr_cnt[c], state.thr_cnt_present[c],
-        g(state.thr_req), g(state.thr_req_present),
-        state.st_cnt_throttled[c],
-        g(state.st_req_flag_present), g(state.st_req_throttled),
-        (state.used_cnt + state.res_cnt)[c],
-        (state.used_cnt_present | state.res_cnt_present)[c],
-        g(state.used_req + state.res_req),
-        g(state.used_req_present | state.res_req_present),
-        on_equal, step3_on_equal, axis=0,
-    )
-    return jnp.where(slot, result, jnp.int8(CHECK_NOT_AFFECTED))
+    Each slot reads its throttle's whole record with one row gather, like
+    an embedding lookup, where gathering the raw state planes element by
+    element took 16 gathers a slot (81 ns a slot on a v5e at 100k pods ×
+    10k throttles, against 7 ns). The record is built once per call, in
+    the same jit, from the state."""
+    table = _gather_table(state, on_equal, step3_on_equal)
+    return _classify_slots(table, *_pod_rows(pods), cols)
 
 
 # Peak-footprint governor for the sparse gather kernels: a [P,K] dispatch
-# materializes ~6 gathered [R,P,K] operands (u32 limbs + presence preds),
-# so an unbounded P×K — the 2048-col rung at the 131072-pod ladder cap is
-# 2.1G elements — cannot be dispatched as one program on a 16G chip. Blocks
-# of ≤ KT_GATHER_CHUNK_ELEMS padded elements (R × P_block × K_padded) run
-# under lax.map: one compiled block body, device-serial blocks, bit-
-# identical statuses. 64M elems ≈ 256M per u32 operand ≈ ~1.5G peak.
+# materializes the gathered records, one row per slot, each padded to a
+# whole number of 128-lane tiles, so an unbounded P×K — the 2048-col rung
+# at the 131072-pod ladder cap is 268M rows, 137G as int32 — cannot be
+# dispatched as one program on a 16G chip. Blocks of ≤
+# KT_GATHER_CHUNK_ELEMS padded elements (P_block × K × 128) run under
+# lax.map: one compiled block body, device-serial blocks, bit-identical
+# statuses. 64M elems ≈ 256M per gathered block.
 try:
     _GATHER_CHUNK_ELEMS = int(
         os.environ.get("KT_GATHER_CHUNK_ELEMS", str(64 * 1024 * 1024))
@@ -280,36 +357,30 @@ except ValueError:
 
 
 def _gather_statuses_blocked(state, pods, cols, on_equal, step3_on_equal):
-    """_gather_statuses, chunked over P when the padded gather footprint
-    exceeds _GATHER_CHUNK_ELEMS. Shapes are static under jit, so the block
-    decomposition is a trace-time decision; P is padded to a whole number
-    of blocks with invalid pods (slot masking already yields
-    CHECK_NOT_AFFECTED there) and sliced back."""
+    """_gather_statuses, chunked over P when the padded footprint of the
+    gathered records exceeds _GATHER_CHUNK_ELEMS. Shapes are static under
+    jit, so the block decomposition is a trace-time decision; the table
+    is built once, P is padded to a whole number of blocks with invalid
+    pods (slot masking already yields CHECK_NOT_AFFECTED there) and sliced
+    back."""
     P, K = cols.shape
-    R = pods.req.shape[1]
-    k_pad = max(K, 128)  # lane-dim tile: K below 128 pads up to 128
-    if P * k_pad * R <= _GATHER_CHUNK_ELEMS:
-        return _gather_statuses(state, pods, cols, on_equal, step3_on_equal)
-    pb = max(1, _GATHER_CHUNK_ELEMS // (k_pad * R))
+    table = _gather_table(state, on_equal, step3_on_equal)
+    rows = _pod_rows(pods)
+    lanes = -(-table.shape[1] // 128) * 128
+    if P * K * lanes <= _GATHER_CHUNK_ELEMS:
+        return _classify_slots(table, *rows, cols)
+    pb = max(1, _GATHER_CHUNK_ELEMS // (K * lanes))
     nb = -(-P // pb)
     pad = nb * pb - P
 
-    def padp(a):
+    def blocks(a, fill=0):
         widths = ((0, pad),) + ((0, 0),) * (a.ndim - 1)
-        return jnp.pad(a, widths)
+        return jnp.pad(a, widths, constant_values=fill).reshape((nb, pb) + a.shape[1:])
 
-    pods_b = PodBatch(
-        valid=padp(pods.valid).reshape(nb, pb),
-        req=padp(pods.req).reshape(nb, pb, R),
-        req_present=padp(pods.req_present).reshape(nb, pb, R),
-    )
-    cols_b = jnp.pad(cols, ((0, pad), (0, 0)), constant_values=-1).reshape(nb, pb, K)
-
-    def block(xs):
-        bpods, bcols = xs
-        return _gather_statuses(state, bpods, bcols, on_equal, step3_on_equal)
-
-    out = lax.map(block, (pods_b, cols_b))  # [nb, pb, K] int8
+    out = lax.map(
+        lambda xs: _classify_slots(table, *xs),
+        tuple(blocks(a) for a in rows) + (blocks(cols, -1),),
+    )  # [nb, pb, K] int8
     return out.reshape(nb * pb, K)[:P]
 
 

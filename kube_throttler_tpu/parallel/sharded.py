@@ -193,10 +193,10 @@ def full_update_step_gather(
     used_cnt = jnp.zeros(T, dtype=jnp.int64).at[tgt].add(1, mode="drop")
     # R-LEADING scatter operands: the naive [P·K, R] update-row matrix
     # tile-pads R=8 → 128 lanes on TPU — a 16× expansion (8.6G at the
-    # 131072-pod ladder cap), the same OOM class the gather kernels hit
-    # (see ops/check.py _gather_statuses). With [R, P·K] rows scattering
-    # into an [R, T] accumulator the huge P·K count rides the un-padded
-    # lane dim and R the sublane dim; transposing back costs one [T,R].
+    # 131072-pod ladder cap), the OOM class the gather kernels once hit.
+    # With [R, P·K] rows scattering into an [R, T] accumulator the huge
+    # P·K count rides the un-padded lane dim and R the sublane dim;
+    # transposing back costs one [T,R].
     req_rows = jnp.broadcast_to(pods.req.T[:, :, None], (R, P_, K)).reshape(R, P_ * K)
     pres_rows = jnp.broadcast_to(
         pods.req_present.T[:, :, None], (R, P_, K)

@@ -173,15 +173,141 @@ def _cols_of_mask(mask: np.ndarray, K: int) -> np.ndarray:
     return out
 
 
-@pytest.mark.parametrize("kind", ["throttle", "clusterthrottle"])
-@pytest.mark.parametrize("on_equal", [False, True])
-@pytest.mark.parametrize("seed", [0, 3])
-def test_gather_matches_compact(kind, on_equal, seed):
+_BIG = 2**62
+# int64 values on the limb boundaries of the sparse kernel's (hi, lo)
+# int32 split and near 2^62, the largest the overflow-free domain allows
+_EDGE = [0, 1, 2, 99, 100, 101, 2**31 - 1, 2**31, 2**32 - 1, 2**32, 2**32 + 1,
+         _BIG - 1, _BIG, _BIG + 1]
+_AU_MAX = _BIG - 2  # used + reserved; with a pod ≤ 2^62 + 1 no sum overflows
+
+
+def _edge_state(rng, T, P, R):
+    """Raw (ThrottleState, PodBatch) planes of width R: absent and zero
+    dims on both sides, pods that request zero, used + reserved one under,
+    at and over thresholds near 2^62 and on limb boundaries, invalid
+    throttles and pods (with data in them), status flags."""
+    from kube_throttler_tpu.ops import PodBatch, ThrottleState
+
+    def present(shape, p):
+        return np.asarray([rng.random() < p for _ in range(int(np.prod(shape)))]).reshape(shape)
+
+    def au_near(thr):
+        au = thr + rng.choice([-1, 0, 1]) if rng.random() < 0.7 else rng.choice(_EDGE)
+        return min(max(au, 0), _AU_MAX)
+
+    thr_req_present, au_req_present = present((T, R), 0.6), present((T, R), 0.7)
+    thr_req = np.zeros((T, R), dtype=np.int64)
+    used_req = np.zeros((T, R), dtype=np.int64)
+    res_req = np.zeros((T, R), dtype=np.int64)
+    for t in range(T):
+        for r in range(R):
+            thr = rng.choice(_EDGE)
+            au = au_near(thr)
+            thr_req[t, r] = thr if thr_req_present[t, r] else 0
+            if au_req_present[t, r]:
+                used_req[t, r] = au // 2
+                res_req[t, r] = au - au // 2
+    thr_cnt_present, au_cnt_present = present((T,), 0.6), present((T,), 0.7)
+    thr_cnt = np.where(thr_cnt_present, [rng.choice([0, 1, 2, 3, 5]) for _ in range(T)], 0)
+    used_cnt = np.where(au_cnt_present, [rng.choice([0, 1, 2, 3]) for _ in range(T)], 0)
+    res_cnt = np.where(au_cnt_present, [rng.choice([0, 1]) for _ in range(T)], 0)
+    st_flag_present = present((T, R), 0.5)
+    state = ThrottleState(
+        valid=present((T,), 0.85),
+        thr_cnt=thr_cnt.astype(np.int64), thr_cnt_present=thr_cnt_present,
+        thr_req=thr_req, thr_req_present=thr_req_present,
+        used_cnt=used_cnt.astype(np.int64), used_cnt_present=au_cnt_present & present((T,), 0.8),
+        used_req=used_req, used_req_present=au_req_present & present((T, R), 0.8),
+        res_cnt=res_cnt.astype(np.int64), res_cnt_present=au_cnt_present & present((T,), 0.5),
+        res_req=res_req, res_req_present=au_req_present & present((T, R), 0.5),
+        st_cnt_throttled=present((T,), 0.2),
+        st_req_throttled=st_flag_present & present((T, R), 0.4),
+        st_req_flag_present=st_flag_present,
+    )
+    resid = thr_req - (used_req + res_req)
+    # a few dims a pod, most on the bitmask's word edges, so that one dim
+    # alone often decides a slot
+    hot = sorted({d for d in (0, 1, 31, 32, R - 1) if d < R})
+    req_present = np.zeros((P, R), dtype=bool)
+    req = np.zeros((P, R), dtype=np.int64)
+    for p in range(P):
+        dims = rng.sample(hot, k=rng.randint(0, 3)) + [rng.randrange(R)] * (rng.random() < 0.3)
+        for r in dims:
+            req_present[p, r] = True
+            if rng.random() < 0.5:
+                # one under, at or over some throttle's step-4 residual
+                v = int(resid[rng.randrange(T), r]) + rng.choice([-1, 0, 1])
+            else:
+                v = rng.choice(_EDGE)
+            req[p, r] = min(max(v, 0), _BIG + 1)
+    pods = PodBatch(valid=present((P,), 0.85), req=req, req_present=req_present)
+    return state, pods
+
+
+def _edge_cols(rng, P, T, K):
+    """int32[P,K] cols: -1 pads anywhere in a row, cols of invalid
+    throttles, duplicates, one all-pad row."""
+    cols = np.asarray(
+        [[-1 if rng.random() < 0.25 else rng.randrange(T) for _ in range(K)] for _ in range(P)],
+        dtype=np.int32,
+    )
+    cols[0, :] = -1
+    cols[1, 1:] = cols[1, 0] = max(int(cols[1, 0]), 0)
+    return cols
+
+
+def _assert_gather_is_dense(state, batch, cols, on_equal, step3):
+    """The sparse statuses are the dense [P,T] check_pods read through
+    the cols (padded slots NOT_AFFECTED), and the compact outputs follow."""
+    from kube_throttler_tpu.ops.check import (
+        check_pods_gather,
+        check_pods_gather_statuses,
+        statuses_to_compact,
+    )
+
+    P, T = batch.req.shape[0], state.thr_req.shape[0]
+    dense = np.asarray(check_pods(state, batch, np.ones((P, T), dtype=bool),
+                                  on_equal=on_equal, step3_on_equal=step3))
+    want = np.where(cols >= 0, np.take_along_axis(dense, np.maximum(cols, 0), axis=1),
+                    CHECK_NOT_AFFECTED)
+    got = np.asarray(check_pods_gather_statuses(
+        state, batch, cols, on_equal=on_equal, step3_on_equal=step3))
+    np.testing.assert_array_equal(got, want)
+    want_counts, want_ok = statuses_to_compact(want)
+    got_counts, got_ok = check_pods_gather(
+        state, batch, cols, on_equal=on_equal, step3_on_equal=step3)
+    np.testing.assert_array_equal(np.asarray(got_counts), np.asarray(want_counts))
+    np.testing.assert_array_equal(np.asarray(got_ok), np.asarray(want_ok))
+
+
+# (kind, on_equal, step3_on_equal, seed, R). R None: throttle objects
+# through the encoders, step 3's flag from the kind. R set: raw planes of
+# that width (40 needs two bitmask words) with int64 edge values, every
+# (on_equal, step3_on_equal) pair.
+_GATHER_CASES = [
+    pytest.param(kind, on_equal, None, seed, None, id=f"{seed}-{on_equal}-{kind}")
+    for seed in (0, 3) for on_equal in (False, True)
+    for kind in ("throttle", "clusterthrottle")
+] + [
+    pytest.param("raw", on_equal, step3, seed, R,
+                 id=f"R{R}-{seed}-{on_equal}-{step3}")
+    for R in (8, 16, 40) for seed in (1, 2)
+    for on_equal in (False, True) for step3 in (False, True)
+]
+
+
+@pytest.mark.parametrize("kind,on_equal,step3,seed,R", _GATHER_CASES)
+def test_gather_matches_compact(kind, on_equal, step3, seed, R):
     """check_pods_gather over [P,K] matched cols must equal
-    check_pods_compact over the equivalent [P,T] mask — counts AND gate."""
+    check_pods_compact over the equivalent [P,T] mask — counts AND gate —
+    and its per-slot statuses the dense check_pods read through the cols."""
     from kube_throttler_tpu.ops import check_pods_gather
 
     rng = random.Random(seed)
+    if R is not None:
+        state, batch = _edge_state(rng, T=24, P=20, R=R)
+        _assert_gather_is_dense(state, batch, _edge_cols(rng, 20, 24, 12), on_equal, step3)
+        return
     throttles, reserved, pods = _build_objects(rng, n_throttles=17, n_pods=23, kind=kind)
     dims = DimRegistry()
     state = encode_throttle_state(throttles, dims, reserved=reserved)
@@ -203,13 +329,33 @@ def test_gather_matches_compact(kind, on_equal, seed):
     )
     np.testing.assert_array_equal(np.asarray(got_counts), np.asarray(want_counts))
     np.testing.assert_array_equal(np.asarray(got_ok), np.asarray(want_ok))
+    _assert_gather_is_dense(state, batch, cols, on_equal, step3)
+
+
+def test_gather_lowers_to_one_row_gather():
+    """The sparse kernel reads each slot's throttle with ONE gather of a
+    whole packed record ([1, W] slices, W = 2 + 4R at R ≤ 32), at both
+    widths and in both the single and the blocked form; the element
+    gathers of the raw state planes it replaced (24 in the lowered
+    module) must not come back unnoticed."""
+    import re
+
+    from kube_throttler_tpu.ops import check_pods_gather
+
+    for R, P, K in ((8, 8, 4), (16, 8, 4), (8, 4096, 256)):  # the last one blocks
+        dims = DimRegistry(capacity=R)
+        state = encode_throttle_state([], dims, capacity=16)
+        batch = encode_pods([], dims, capacity=P)
+        text = check_pods_gather.lower(state, batch, np.full((P, K), -1, np.int32)).as_text()
+        slices = re.findall(r'"stablehlo\.gather".*?slice_sizes = array<i64: ([\d, ]+)>', text)
+        assert slices == [f"1, {2 + 4 * R}"], (R, P, K, slices)
 
 
 @pytest.mark.parametrize("seed", [0, 7])
 def test_gather_blocked_matches_unblocked(seed, monkeypatch):
     """The P-chunked gather decomposition (lax.map blocks, activated when
-    P×K_pad×R exceeds KT_GATHER_CHUNK_ELEMS — the r5 full-scale TPU OOM
-    fix) must be bit-identical to the single-dispatch path, including when
+    the gathered records' P×K×128 padded elements exceed
+    KT_GATHER_CHUNK_ELEMS — the r5 full-scale TPU OOM fix) must be bit-identical to the single-dispatch path, including when
     P does not divide evenly into blocks."""
     from kube_throttler_tpu.ops import check, check_pods_gather
 
@@ -227,10 +373,9 @@ def test_gather_blocked_matches_unblocked(seed, monkeypatch):
     # threshold (read at trace time) must be exercised through the raw
     # functions for the monkeypatch to take effect
     want = np.asarray(check._gather_statuses(state, batch, cols, False, True))
-    # force ~4-row blocks (29 pods ⇒ a ragged final block exercises padding)
-    monkeypatch.setattr(
-        check, "_GATHER_CHUNK_ELEMS", 4 * max(cols.shape[1], 128) * batch.req.shape[1]
-    )
+    # force 4-pod blocks of 128-lane records (29 pods ⇒ a ragged final
+    # block exercises padding)
+    monkeypatch.setattr(check, "_GATHER_CHUNK_ELEMS", 4 * cols.shape[1] * 128)
     got = np.asarray(check._gather_statuses_blocked(state, batch, cols, False, True))
     np.testing.assert_array_equal(got, want)
     # and through the compact reduction (counts + schedulable gate)
