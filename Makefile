@@ -1,7 +1,7 @@
 # Dev workflow targets (reference Makefile parity, minus Go/kind).
 PY ?= python
 
-.PHONY: test test-stress race-test crash-test ha-test reshard-test net-chaos shm-chaos upgrade-test scenario-test shard-scenario reshard-scenario preempt-scenario partition-scenario replica-scenario scenario-regression scenario-hunt scenario-hunt-smoke scenario-hunt-long scenario-hunt-nightly lint ci gen bench bench-quick walkthrough smoke serve clean native image dev-cluster dev-run dev-teardown
+.PHONY: test test-stress race-test crash-test ha-test reshard-test net-chaos shm-chaos upgrade-test scenario-test shard-scenario reshard-scenario preempt-scenario partition-scenario replica-scenario scenario-regression scenario-hunt scenario-hunt-smoke scenario-hunt-long scenario-hunt-nightly lint ci gen walkthrough smoke serve clean native image dev-cluster dev-run dev-teardown
 
 native:          ## build the C++ selector row-match engine (auto-built on import too)
 	$(PY) -c "from kube_throttler_tpu.native import load; import sys; \
@@ -83,7 +83,7 @@ scenario-hunt-nightly: ## nightly cadence (hack/ci.sh comments): the long tier a
 		--report hunt-nightly-report.json
 
 lint:            ## 15-checker static analyzer (locks, purity, registries, blocking, threads, excsafety, protocol, dtype, donation, retrace, envguard, epochs, deadlines, taint) + syntax sanity
-	$(PY) -m compileall -q kube_throttler_tpu tools bench.py __graft_entry__.py
+	$(PY) -m compileall -q kube_throttler_tpu tools __graft_entry__.py
 	$(PY) -m kube_throttler_tpu.analysis
 
 ci:              ## the CI gate: lint + fast smoke tier (hack/ci.sh) — lint failures fail CI, not review
@@ -94,12 +94,6 @@ gen:             ## regenerate deploy/crd.yaml from the typed API model
 
 image:           ## container image for deploy/deployment.yaml (Dockerfile)
 	tools/build_image.sh
-
-bench:           ## the five BASELINE.json configs (one JSON line on stdout)
-	$(PY) bench.py
-
-bench-quick:
-	$(PY) bench.py --quick
 
 walkthrough:     ## reference README walkthrough end-to-end
 	$(PY) examples/walkthrough.py

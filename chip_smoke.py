@@ -7,7 +7,7 @@
 One process owns the chip: this script starts ONE daemon child
 (``python -m kube_throttler_tpu.cli serve`` with ``JAX_PLATFORMS=tpu``)
 and never initializes a JAX backend itself (asserted at the end). The
-state is the served-stack state bench.py loads (100k bound Running pods ×
+state is ``scenarios.measure.served_state`` (100k bound Running pods ×
 10k throttles in 500 selector groups, from ``--seed``), handed to the
 daemon as a snapshot in a scratch ``--data-dir`` that ``serve`` recovers
 from. The host reference (``Throttle.check_throttled_for``, api/types.py)

@@ -16,15 +16,15 @@ recompile on object churn (they recompile only on capacity growth, which is
 geometric and rare).
 
 Writes arrive synchronously from store watch events (cheap row updates —
-same contract as informer handlers); reads (``check_pod``,
-``check_batch``) are served from device.
+same contract as informer handlers). Batch reads (``check_batch``, the
+tick) are served from device; ``check_pod`` classifies a single pod on
+the host from the staging rows, and on device only above
+``indexed_check_max`` affected throttles.
 """
 
 from __future__ import annotations
 
 import logging
-import os
-import threading
 import time
 import weakref
 from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
@@ -65,14 +65,6 @@ _EPOCH_ASSERT = _epochassert.enabled()
 AnyThrottle = Union[Throttle, ClusterThrottle]
 
 
-def _next_rung(k: int) -> int:
-    """One step up the shape ladder: ×4 below 128, ×2 above. The single
-    definition both _next_pow2 and _bucket_ladder derive from — if the
-    live bucketing and the prewarm walk ever disagreed, serving would hit
-    mid-burst compiles on rungs prewarm never visited."""
-    return k * (4 if k < 128 else 2)
-
-
 def _next_pow2(n: int, lo: int = 8) -> int:
     """Smallest ladder rung ≥ n — THE shape-bucketing policy: every
     dynamically-sized device index/batch pads to one of these so the set
@@ -80,30 +72,13 @@ def _next_pow2(n: int, lo: int = 8) -> int:
     ladder steps ×4 below 128 and ×2 above (8, 32, 128, 256, 512, …):
     small-burst sizes vary the most, so coarse rungs there cut the
     distinct-shape count (every extra shape is a full XLA compile —
-    ~10-100ms CPU, seconds on a cold TPU, and prewarm() has
-    to walk the whole ladder), while capping padding waste at 2× for the
-    large shapes whose execution cost is real. (Name kept from the
-    original pure-pow2 policy; rungs are now the sparse ladder above.)"""
+    ~10-100ms CPU, seconds on a cold TPU), while capping padding waste at
+    2× for the large shapes whose execution cost is real. (Name kept from
+    the original pure-pow2 policy.)"""
     k = lo
     while k < n:
-        k = _next_rung(k)
+        k *= 4 if k < 128 else 2
     return k
-
-
-def _bucket_ladder(ladder_max: int, lo: int = 8) -> List[int]:
-    """The rungs _next_pow2 can produce, ≤ ladder_max (prewarm walks these)."""
-    out = []
-    k = lo
-    while k <= ladder_max:
-        out.append(k)
-        k = _next_rung(k)
-    return out
-
-
-# top rung of the prewarm bucket ladder for the CHECK kernels (the whole
-# aggregate data plane is host numpy — steal/apply_agg_work — so no
-# aggregate shapes exist to cap or warm)
-CHECK_LADDER_MAX = 512
 
 
 def _host_classify_rows(rows, pod_req, pod_present, on_equal, step3_on_equal):
@@ -112,9 +87,9 @@ def _host_classify_rows(rows, pod_req, pod_present, on_equal, step3_on_equal):
     rows that already live in host staging; any device dispatch round
     trip costs more than the arithmetic. The 4-step semantics are kept
     line-for-line with the kernel and pinned by
-    the device-vs-host parity test (test_check_kernel's
-    test_host_single_check_matches_device_kernel, which forces both
-    routes); invalid columns report CHECK_NOT_AFFECTED like the kernels'
+    the host-vs-device parity test (test_check_kernel's
+    test_host_single_check_matches_device_kernel, which runs check_pod on
+    both routes); invalid columns report CHECK_NOT_AFFECTED like the kernels'
     slot masking."""
     (
         valid,
@@ -166,20 +141,6 @@ def _host_classify_rows(rows, pod_req, pod_present, on_equal, step3_on_equal):
     return np.where(valid, out, np.int8(CHECK_NOT_AFFECTED))
 
 
-_AGG_DEVICE_DELTAS: Optional[bool] = None
-
-
-def _agg_device_deltas() -> bool:
-    """True routes pending-delta bursts through the real
-    ``apply_pod_deltas_batched`` device kernel instead of its host mirror
-    (KT_AGG_DEVICE_DELTAS=1 — see _KindState.apply_pending_batched).
-    Resolved once; the parity test toggles the cache directly."""
-    global _AGG_DEVICE_DELTAS
-    if _AGG_DEVICE_DELTAS is None:
-        _AGG_DEVICE_DELTAS = os.environ.get("KT_AGG_DEVICE_DELTAS") == "1"
-    return _AGG_DEVICE_DELTAS
-
-
 _cls_lib = None
 _cls_lib_tried = False
 
@@ -205,7 +166,7 @@ def _native_classify_cols(lib, ks, cols, pod_req_row, pod_present_row, on_equal,
     allocation; the identity check re-registers after capacity growth
     (ensure_capacity reallocates, logarithmically under the ladder).
     Semantics are pinned to _host_classify_rows (numpy tier) AND the
-    device kernel by test_host_single_check_matches_device_kernel, whose
+    dense device kernel by test_host_single_check_matches_device_kernel, whose
     final section forces the numpy tier through the module lib cache."""
     planes = (
         ks.thr_valid,
@@ -290,7 +251,6 @@ class _KindState:
         self._cls_cache = None
         self._cls_scratch = None
         self._device_state: Optional[ThrottleState] = None
-        self._device_packed = None  # CheckPrecompPacked cache for check_pod
         self._device_pods: Optional[PodBatch] = None
         self._device_mask = None
         # sparse companion of the mask for batch checks: int32[pcap, K]
@@ -729,7 +689,6 @@ class _KindState:
                 }
             )
             self._dirty_thr_cols.clear()
-            self._device_packed = None  # derived cache follows the state
             return self._device_state
         if self.dirty_throttles or self._device_state is None or self._dirty_thr_cols:
             self._device_state = ThrottleState(
@@ -740,18 +699,7 @@ class _KindState:
             )
             self.dirty_throttles = False
             self._dirty_thr_cols.clear()
-            self._device_packed = None  # derived cache follows the state
         return self._device_state
-
-    def device_packed(self):
-        """Packed residual-form precomp for the indexed single-pod check,
-        rebuilt lazily on throttle-state change."""
-        from ..ops.fastcheck import pack_check_state, precompute_check_state
-
-        state = self.device_state()  # refreshes + clears dirty_throttles
-        if self._device_packed is None:
-            self._device_packed = pack_check_state(precompute_check_state(state))
-        return self._device_packed
 
     def device_pods(self, need_mask: bool = True) -> Tuple[PodBatch, Optional[jnp.ndarray]]:
         """Device pod arrays + (optionally) the [P,T] device mask.
@@ -1199,15 +1147,12 @@ class _KindState:
         ``apply_pod_deltas_batched``, which until PR 5 only the sharded
         tick used (parallel/sharded.py sharded_apply_deltas).
 
-        The aggregates are HOST-resident (see apply_agg_work), so the
-        default route is the kernel's exact host mirror: the same flattened
-        [N·K] scatter-add over the same (ids, sign, req, present) encoding
-        — np.add.at commutes and associates exactly in int64 like the
-        device scatter, and the parity is pinned by
-        tests/test_batch_ingest.py against the real kernel.
-        ``KT_AGG_DEVICE_DELTAS=1`` opts into dispatching the actual jitted
-        kernel instead (accelerator-resident aggregate experiments); both
-        routes are bit-identical by construction.
+        The aggregates are HOST-resident (see apply_agg_work), so this is
+        the kernel's exact host mirror: the same flattened [N·K]
+        scatter-add over the same (ids, sign, req, present) encoding —
+        np.add.at commutes and associates exactly in int64 like the device
+        scatter, and the parity is pinned by tests/test_batch_ingest.py
+        against the real kernel.
 
         Caller holds the per-kind agg lock. A per-entry Python loop of
         small adds measured ~16ms per 256-key drain at cfg5 max rate; this
@@ -1215,20 +1160,6 @@ class _KindState:
         if not pending:
             return
         ids, sign, req, pres = self._pending_batch_arrays(pending)
-        if _agg_device_deltas():
-            from ..ops.aggregate import apply_pod_deltas_batched
-
-            cnt, reqa, ctb = apply_pod_deltas_batched(
-                jnp.asarray(self.agg_cnt),
-                jnp.asarray(self.agg_req),
-                jnp.asarray(self.agg_contrib),
-                jnp.asarray(ids), jnp.asarray(sign),
-                jnp.asarray(req), jnp.asarray(pres),
-            )
-            self.agg_cnt = np.asarray(cnt)
-            self.agg_req = np.asarray(reqa)
-            self.agg_contrib = np.asarray(ctb, dtype=np.int32)
-            return
         flat_ids = ids.ravel()
         flat_sign = sign.ravel()
         valid = flat_ids < self.agg_cnt.shape[0]  # strip the tcap padding
@@ -1333,18 +1264,10 @@ class DeviceStateManager:
         self.dims = dims or DimRegistry()
         self._lock = make_rlock("devicestate.main")
         self.tracer = NoopTracer()  # set by the plugin; times device checks
-        # check_pod uses the indexed hot path up to this many affected
-        # throttles, the dense [1,T] sweep beyond (tunable for tests)
+        # check_pod classifies on the host up to this many affected
+        # throttles, with the dense [1,T] device sweep beyond (tunable for
+        # tests)
         self.indexed_check_max = 1024
-        # single-pod check routing, resolved lazily from the backend on
-        # first use (see _resolve_single_check_route): accelerators always
-        # route host (one small dispatch round trip on a local v5e is
-        # ~1 ms, 0.94-1.08 ms in chip_smoke.py, for [K,R] arithmetic); on
-        # the CPU backend the native C++ host tier beats the fused XLA
-        # kernel, which in turn beats the numpy tier — so kernel only
-        # without the native lib. KT_SINGLE_CHECK_DEVICE=1/0 forces either
-        # route (parity tests force both).
-        self._single_check_device: Optional[bool] = None
         # columnar store: both kinds' indexes share the arena's intern
         # pool (one interning per label string per process), retain no pod
         # objects (Store.materialize_pod resolves the rare full-object
@@ -1521,9 +1444,6 @@ class DeviceStateManager:
         Call after cache sync, before serving."""
         import jax
 
-        from ..ops.fastcheck import fast_check_pod_packed
-
-        ladder = _bucket_ladder(CHECK_LADDER_MAX)
         # warm dispatches EXECUTE, not just compile, so walk only shapes the
         # serving path can actually hit. The aggregate data plane needs NO
         # warming at all: deltas AND rebases are host numpy now
@@ -1535,26 +1455,9 @@ class DeviceStateManager:
         shapes: List[str] = []
         for kind in ("throttle", "clusterthrottle"):
             ks = self._kind(kind)
-            with self._lock:
-                ks.ensure_capacity()
-                packed = ks.device_packed()
-                R = ks.R
-            # the indexed single-pod check (the PreFilter fast path): the
-            # K-affected buckets actually seen are small; warm the bottom
-            # two rungs with the kind's live step3 variant (pre_filter
-            # always passes on_equal=False, plugin.go:153,165)
+            # the kind's live step3 variant (pre_filter_batch and the tick
+            # always pass on_equal=False, plugin.go:153,165)
             step3 = kind == "throttle"
-            row_req = np.zeros(R, dtype=np.int64)
-            row_present = np.zeros(R, dtype=bool)
-            for kb in ladder[:2]:
-                idx = np.zeros(kb, dtype=np.int32)
-                idx_valid = np.zeros(kb, dtype=bool)
-                np.asarray(
-                    fast_check_pod_packed(
-                        packed, row_req, row_present, idx, idx_valid, False, step3
-                    )
-                )
-                shapes.append(f"{kind}:fast_check[K={kb}]")
             # the sparse [P,K] batch-triage kernel (the served
             # pre_filter_batch path): walk the K-ladder rungs the sparse
             # path can occupy — a pod relabel can grow the rung at runtime
@@ -1565,6 +1468,7 @@ class DeviceStateManager:
             # masks, where one [P,T,R] execution is exactly the
             # multi-second dispatch prewarm must not issue.
             with self._lock:
+                ks.ensure_capacity()
                 state = ks.device_state()
                 pods, _ = ks.device_pods(need_mask=False)
                 live_cols = ks.device_cols()
@@ -2324,29 +2228,6 @@ class DeviceStateManager:
 
     # -- queries ----------------------------------------------------------
 
-    def _resolve_single_check_route(self) -> bool:
-        """True ⇒ single-pod checks use the device kernel; False ⇒ the host
-        classifier. Resolved once from KT_SINGLE_CHECK_DEVICE (1/0 forces)
-        or the live backend+tiers: on accelerators always host (a dispatch
-        is a real round trip there — ~1 ms on a local v5e, 0.94-1.08 ms in
-        chip_smoke.py — for [K,R] arithmetic). On the CPU backend it
-        depends on the host TIER: the native C++ classifier beats the
-        fused XLA kernel (~100µs vs ~157µs per full-scale served decision,
-        measured), but
-        the numpy tier loses to it (~30 tiny numpy ops at ~86µs vs the
-        kernel's ~43µs) — so kernel only when the native lib is absent."""
-        if self._single_check_device is None:
-            import jax
-
-            forced = os.environ.get("KT_SINGLE_CHECK_DEVICE")
-            if forced in ("0", "1"):
-                self._single_check_device = forced == "1"
-            else:
-                self._single_check_device = (
-                    jax.default_backend() == "cpu" and _native_cls_lib() is None
-                )
-        return self._single_check_device
-
     @staticmethod
     def _gather_check_rows(ks: _KindState, cols: np.ndarray):
         """Coherent [K]-row snapshot of everything the 4-step check reads,
@@ -2371,10 +2252,8 @@ class DeviceStateManager:
         → ([1,R] int64, [1,R] bool). Identical for both kinds and across
         scheduler retries of the same stored object — memoized per pod
         OBJECT (a pod update is a new object; GC evicts via weakref
-        finalizer). Caller holds the main lock. Shared by check_pod and
-        check_pods_multi: the encode is the dominant per-pod host cost
-        (~25µs of Fraction math), so an unmemoized batch path would erase
-        the fused dispatch's win."""
+        finalizer). Caller holds the main lock. The encode is the dominant per-pod
+        host cost of check_pod (~25µs of Fraction math)."""
         cached = self._encode_cache.get(id(pod))
         if cached is not None and cached[0]() is pod and cached[1] == ks.R:
             return cached[2], cached[3]
@@ -2549,23 +2428,28 @@ class DeviceStateManager:
 
     def check_pod(self, pod: Pod, kind: str, on_equal: bool = False) -> Dict[str, str]:
         """Single-pod check → {throttle_key: status_name} over affected
-        throttles. The device kernel sees a 1-row pod batch + its mask row.
+        throttles. Up to ``indexed_check_max`` affected throttles it runs
+        on the host: a single pod's check is a [K,R] computation over rows
+        that live in host staging anyway, and host arithmetic beats a
+        device round trip (~1 ms on a local v5e, chip_smoke.py) by orders
+        of magnitude. The native tier runs the whole 4-step pass in C++
+        against the live planes under the lock (sub-µs; the ~20-numpy-op
+        pass measured ~50µs/kind at 100k×10k); without the native library
+        the numpy tier snapshots the rows under the lock and classifies
+        outside it. Beyond that the dense ``check_pods`` sees a 1-row pod
+        batch and its mask row.
 
         Concurrency: the lock guards only the HOST-side snapshot (request
         encode, mask row copy, device-handle grab, key decode tables); the
-        kernel dispatch + blocking device read — the dominant cost — run
-        outside it. The device caches are replaced functionally (``.at[]``
-        scatters / wholesale re-uploads build NEW arrays), so a grabbed
-        handle is an immutable point-in-time snapshot and concurrent
-        checkers don't queue behind each other or behind writers — the
-        intent of the reference's RWMutex + keymutex split
-        (reserved_resource_amounts.go:154-170)."""
-        from ..ops.fastcheck import fast_check_pod_packed
-
+        dense dispatch + blocking device read run outside it. The device
+        caches are replaced functionally (``.at[]`` scatters / wholesale
+        re-uploads build NEW arrays), so a grabbed handle is an immutable
+        point-in-time snapshot and concurrent checkers don't queue behind
+        each other or behind writers — the intent of the reference's
+        RWMutex + keymutex split (reserved_resource_amounts.go:154-170)."""
         with self.tracer.trace("device_check"):
             dense = None
             rows = None
-            packed = None
             out_k = None
             with self._lock:
                 ks = self.throttle if kind == "throttle" else self.clusterthrottle
@@ -2588,9 +2472,7 @@ class DeviceStateManager:
                 step3 = True if kind == "throttle" else on_equal
                 cols = np.nonzero(mask_row[0])[0]
                 if cols.size == 0:
-                    # no affected throttles — nothing to classify; skip the
-                    # kernel dispatch entirely (with an empty clusterthrottle
-                    # set this halves every pre_filter's device round trips)
+                    # no affected throttles — nothing to classify
                     return {}
                 if cols.size <= self.indexed_check_max:
                     # tolist() converts the whole cols vector in C; the
@@ -2599,57 +2481,22 @@ class DeviceStateManager:
                     with ks.index._lock:  # noqa: SLF001 — declared guard
                         ck = ks.index._col_keys
                         col_keys = list(map(ck.get, cols.tolist()))
-                    if not self._resolve_single_check_route():
-                        # HOST path — the default on every backend when
-                        # the native tier loads: a single pod's check is a
-                        # [K,R] computation over rows that live in host
-                        # staging anyway. On accelerators host arithmetic
-                        # beats a device ROUND TRIP (~1 ms on a local v5e,
-                        # chip_smoke.py) by orders of magnitude; on CPU
-                        # the native tier beats even the fused XLA
-                        # kernel. Native tier runs the whole 4-step pass
-                        # in C++ against the live planes under the lock
-                        # (sub-µs — the ~20-numpy-op pass measured
-                        # ~50µs/kind at 100k×10k); numpy tier snapshots
-                        # rows under the lock and classifies outside. The
-                        # device keeps the BATCH surfaces, where
-                        # parallelism actually pays. (CPU without the
-                        # native lib routes to the fused kernel instead —
-                        # see _resolve_single_check_route.)
-                        lib = _native_cls_lib()
-                        if lib is not None:
-                            out_k = _native_classify_cols(
-                                lib, ks, cols, row_req[0], row_present[0],
-                                on_equal, step3,
-                            )
-                        else:
-                            rows = self._gather_check_rows(ks, cols)
+                    lib = _native_cls_lib()
+                    if lib is not None:
+                        out_k = _native_classify_cols(
+                            lib, ks, cols, row_req[0], row_present[0],
+                            on_equal, step3,
+                        )
                     else:
-                        packed = ks.device_packed()
+                        rows = self._gather_check_rows(ks, cols)
                 else:
                     dense = (ks.device_state(), dict(ks.index._thr_cols))
 
-            # ---- outside the lock: dispatch + blocking read + decode ----
+            # ---- outside the lock: classify or dispatch, then decode ----
             if dense is None:
                 if rows is not None:
                     out_k = _host_classify_rows(
                         rows, row_req[0], row_present[0], on_equal, step3
-                    )
-                elif out_k is None:
-                    # device A/B path (KT_SINGLE_CHECK_DEVICE=1): classify
-                    # the K affected rows against the cached packed
-                    # precomp — O(K·R) device AND host work, independent
-                    # of tcap. K buckets (powers of two) bound compiles.
-                    k = _next_pow2(cols.size)
-                    idx = np.zeros(k, dtype=np.int32)
-                    idx_valid = np.zeros(k, dtype=bool)
-                    idx[: cols.size] = cols
-                    idx_valid[: cols.size] = True
-                    out_k = np.asarray(
-                        fast_check_pod_packed(
-                            packed, row_req[0], row_present[0],
-                            idx, idx_valid, on_equal, step3,
-                        )
                     )
                 result = {}
                 for slot, key in enumerate(col_keys):
@@ -2669,140 +2516,6 @@ class DeviceStateManager:
                 if out[col] != CHECK_NOT_AFFECTED:
                     result[key] = STATUS_NAMES[int(out[col])]
             return result
-
-    def check_pods_multi(
-        self, pod_list: Sequence[Pod], kind: str, on_equal: bool = False
-    ) -> List[Dict[str, str]]:
-        """Several DISTINCT pods classified in one call — the
-        micro-batching front-end's kernel. Same per-pod result shape as
-        ``check_pod`` ({throttle_key: status_name}).
-
-        Routing mirrors ``check_pod``'s resolver: on the HOST route the
-        native classifier runs B sub-µs passes under the snapshot lock —
-        no device involvement at all, which matters most where the
-        coalescer is aimed (a fused device dispatch still pays a full
-        dispatch round trip per window). On the device route it stays ONE
-        fused dispatch bucketed on (B, K) ladder rungs.
-
-        Host-side snapshot under the lock (encode + mask rows + state
-        handles); the device dispatch and all decode run outside — same
-        locking discipline as check_pod."""
-        from ..ops.check import check_pods_gather_statuses
-
-        if not pod_list:
-            return []
-        native_out = None
-        host_rows = None
-        with self._lock:
-            ks = self.throttle if kind == "throttle" else self.clusterthrottle
-            ks.ensure_capacity()
-            R, tcap = ks.R, ks.tcap
-            step3 = True if kind == "throttle" else on_equal
-            host_route = not self._resolve_single_check_route()
-            rows, colss = [], []
-            for pod in pod_list:
-                row_req, row_present = self._encoded_row(ks, pod)
-                prow = ks.index.pod_row(pod.key)
-                if prow is not None:
-                    cols = ks.index.row_cols(prow)
-                else:
-                    with ks.index._lock:  # noqa: SLF001 — same-package access
-                        rowm = ks.index.match_row_cached_locked(pod) & ks.index._thr_valid
-                    cols = np.nonzero(rowm[:tcap])[0]
-                rows.append((row_req, row_present))
-                colss.append(cols.astype(np.int32))
-            if ks.R != R:
-                # a mid-batch pod introduced a never-seen resource name:
-                # encode_pod_requests_into grew ks.R and reallocated the
-                # staging planes, leaving EARLIER pods' encoded rows at the
-                # old width. The native tier re-registers planes at the new
-                # R and would read pod_req[r]/pod_present[r] past the end of
-                # those shorter rows — silent garbage verdicts (the device
-                # path at least failed loudly on the shape mismatch).
-                # Re-encode the whole batch: the encode memo keys on ks.R,
-                # so stale-width entries miss and fresh [1, ks.R] rows come
-                # back; the R-grown pod's entry is already current and hits.
-                R = ks.R
-                rows = [self._encoded_row(ks, pod) for pod in pod_list]
-            # host tiers only while every pod's K is indexed-sized: the
-            # lock-held native work stays ≤ B × indexed_check_max × R, and
-            # an oversize (near-dense) pod sends the whole batch to the
-            # fused dispatch, which runs outside the lock (check_pod's
-            # dense-fallback analog)
-            host_route = host_route and all(
-                c.size <= self.indexed_check_max for c in colss
-            )
-            state = None
-            if host_route:
-                lib = _native_cls_lib()
-                if lib is not None:
-                    native_out = [
-                        _native_classify_cols(
-                            lib, ks, cc, rq[0], rp[0], on_equal, step3
-                        )
-                        for (rq, rp), cc in zip(rows, colss)
-                    ]
-                else:
-                    # numpy tier: [K]-row snapshots under the lock,
-                    # classification outside (mirrors check_pod)
-                    host_rows = [self._gather_check_rows(ks, cc) for cc in colss]
-            else:
-                state = ks.device_state()
-            with ks.index._lock:  # noqa: SLF001 — declared guard
-                col_keys = dict(ks.index._col_keys)
-
-        if host_rows is not None:
-            native_out = [
-                _host_classify_rows(hr, rq[0], rp[0], on_equal, step3)
-                for hr, (rq, rp) in zip(host_rows, rows)
-            ]
-        if native_out is not None:
-            results: List[Dict[str, str]] = []
-            for cc, out_k in zip(colss, native_out):
-                res: Dict[str, str] = {}
-                for slot, col in enumerate(cc.tolist()):
-                    status = int(out_k[slot])
-                    if status != CHECK_NOT_AFFECTED:
-                        key = col_keys.get(col)
-                        if key is not None:
-                            res[key] = STATUS_NAMES[status]
-                results.append(res)
-            return results
-
-        B = len(pod_list)
-        Bp = _next_pow2(B, lo=4)
-        K = _next_pow2(max((c.size for c in colss), default=1) or 1, lo=4)
-        req = np.zeros((Bp, R), dtype=np.int64)
-        present = np.zeros((Bp, R), dtype=bool)
-        valid = np.zeros(Bp, dtype=bool)
-        cols_arr = np.full((Bp, K), -1, dtype=np.int32)
-        for i, ((rq, rp), cc) in enumerate(zip(rows, colss)):
-            req[i] = rq[0]
-            present[i] = rp[0]
-            valid[i] = True
-            cols_arr[i, : cc.size] = cc
-        # numpy args go straight into the jitted call: jit's argument path
-        # converts them ~an order of magnitude cheaper than explicit
-        # jnp.asarray device_puts (measured 361µs vs 39µs per call here)
-        batch = PodBatch(valid=valid, req=req, req_present=present)
-        out = np.asarray(
-            check_pods_gather_statuses(
-                state, batch, cols_arr,
-                on_equal=on_equal, step3_on_equal=step3,
-            )
-        )
-        results: List[Dict[str, str]] = []
-        for i in range(B):
-            res: Dict[str, str] = {}
-            cc = colss[i]
-            for slot in range(cc.size):
-                status = int(out[i, slot])
-                if status != CHECK_NOT_AFFECTED:
-                    key = col_keys.get(int(cc[slot]))
-                    if key is not None:
-                        res[key] = STATUS_NAMES[status]
-            results.append(res)
-        return results
 
     def _grab_batch_handles(self, kind: str, on_equal: bool):
         """Under the caller's lock: one kind's immutable device handles +

@@ -39,9 +39,11 @@ from __future__ import annotations
 
 import os
 from functools import partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from .schema import PodBatch, ThrottleState
@@ -226,6 +228,86 @@ def check_pods_gather(state: ThrottleState, pods: PodBatch, cols: jnp.ndarray,
     )
 
 
+class CheckPrecomp(NamedTuple):
+    """Pod-independent per-throttle tensors of the residual form.
+
+    Every addition in steps 3-4 involves only pod-independent terms, so
+
+        used + reserved + pod  >  threshold
+    ⟺  pod  >  threshold - (used + reserved)          (exact in int64)
+
+    and step 3 (``used + reserved`` vs threshold) has no pod term at all.
+    The saturation flags for both onEqual variants, the step-4 residual
+    and the count verdicts (a pod's count contribution is always exactly
+    1) are computed once per state, leaving the per-slot work to compares.
+    ``thr - (used + reserved)`` cannot overflow for any state this
+    framework produces: used/reserved are sums of non-negative pod
+    amounts, and thresholds are admission-scale quantities ≪ 2^62."""
+
+    valid: jnp.ndarray  # bool[T]
+    thr_req: jnp.ndarray  # int64[T,R] — step-1 compare target
+    thr_req_present: jnp.ndarray  # bool[T,R]
+    exceeds_cnt: jnp.ndarray  # bool[T] — 1 > thr_cnt (step 1, onEqual=False)
+    st_cnt: jnp.ndarray  # bool[T] — status.throttled count flag
+    st_req: jnp.ndarray  # bool[T,R] — status.throttled request flag ∧ present
+    sat_cnt_ge: jnp.ndarray  # bool[T] — step-3 count, onEqual=True
+    sat_cnt_gt: jnp.ndarray  # bool[T] — step-3 count, onEqual=False
+    sat_req_ge: jnp.ndarray  # bool[T,R]
+    sat_req_gt: jnp.ndarray  # bool[T,R]
+    resid: jnp.ndarray  # int64[T,R] — thr - (used+reserved), step-4 target
+    over_cnt_ge: jnp.ndarray  # bool[T] — step-4 count, onEqual=True
+    over_cnt_gt: jnp.ndarray  # bool[T]
+
+
+@jax.jit
+def precompute_check_state(state: ThrottleState) -> CheckPrecomp:
+    au_cnt = state.used_cnt + state.res_cnt
+    au_cnt_present = state.used_cnt_present | state.res_cnt_present
+    au_req = state.used_req + state.res_req
+    au_req_present = state.used_req_present | state.res_req_present
+
+    sat_cnt_base = state.thr_cnt_present & au_cnt_present
+    sat_req_base = state.thr_req_present & au_req_present
+
+    return CheckPrecomp(
+        valid=state.valid,
+        thr_req=state.thr_req,
+        thr_req_present=state.thr_req_present,
+        exceeds_cnt=state.thr_cnt_present & (1 > state.thr_cnt),
+        st_cnt=state.st_cnt_throttled,
+        st_req=state.st_req_flag_present & state.st_req_throttled,
+        sat_cnt_ge=sat_cnt_base & (au_cnt >= state.thr_cnt),
+        sat_cnt_gt=sat_cnt_base & (au_cnt > state.thr_cnt),
+        sat_req_ge=sat_req_base & (au_req >= state.thr_req),
+        sat_req_gt=sat_req_base & (au_req > state.thr_req),
+        resid=state.thr_req - au_req,
+        # step-4 count: total count = au_cnt + 1, always present
+        over_cnt_ge=state.thr_cnt_present & (au_cnt + 1 >= state.thr_cnt),
+        over_cnt_gt=state.thr_cnt_present & (au_cnt + 1 > state.thr_cnt),
+    )
+
+
+# xor bias turning unsigned order into signed; numpy, so importing this
+# module builds no device array and initializes no backend
+_BIAS = np.int32(-(2**31))
+
+
+def _split_limbs(x: jnp.ndarray):
+    """int64 → (hi int32 signed, lo int32 biased): hi = x >> 32, lo = the
+    low 32 bits biased by 2^31 so that a signed compare of lo is the
+    unsigned one. A lexicographic (hi, lo) compare is then exactly the
+    s64 compare, in native int32 ops instead of the X64 rewriter's
+    emulation."""
+    hi = (x >> 32).astype(jnp.int32)
+    lo = jnp.bitwise_xor((x & 0xFFFFFFFF).astype(jnp.uint32).astype(jnp.int32), _BIAS)
+    return hi, lo
+
+
+def _limb_gt(a_hi, a_lo, b_hi, b_lo):
+    """(a > b) for s64 split into (signed hi, biased lo)."""
+    return (a_hi > b_hi) | ((a_hi == b_hi) & (a_lo > b_lo))
+
+
 # Flag bits of a gathered record's first word.
 _F_VALID, _F_EXCEEDS_CNT, _F_ACTIVE_CNT, _F_OVER_CNT = 1, 2, 4, 8
 _INT64_MAX = jnp.iinfo(jnp.int64).max
@@ -243,7 +325,7 @@ def _bitmask(bits):
 
 def _gather_table(state, on_equal, step3_on_equal):
     """The pod-independent record of each throttle, int32[T, 1 + nw + 4R]
-    (nw = ceil(R/32)), in the residual form of ``fastcheck``:
+    (nw = ceil(R/32)), in the residual form of ``CheckPrecomp``:
 
     - word 0, flags: valid, step-1 count exceeds, step-2/3 count active
       (``step3_on_equal`` variant), step-4 count over (``on_equal``);
@@ -251,13 +333,10 @@ def _gather_table(state, on_equal, step3_on_equal):
     - per dim, the step-1 target ``thr_req`` and the step-4 target
       ``thr − (used + reserved)`` (less 1 under ``on_equal``, so both
       compares are strict; exact, as the residual cannot be int64 min
-      for any state ``fastcheck``'s overflow note admits), each as (hi,
+      for any state ``CheckPrecomp``'s overflow note admits), each as (hi,
       lo) int32 limbs, hi and lo planes of R words each. An absent
       threshold dim holds int64 max, which no pod request exceeds.
     """
-    from .fastcheck import precompute_check_state
-    from .pallas_check import _split_limbs
-
     pre = precompute_check_state(state)
     sat_cnt = pre.sat_cnt_ge if step3_on_equal else pre.sat_cnt_gt
     sat_req = pre.sat_req_ge if step3_on_equal else pre.sat_req_gt
@@ -280,8 +359,6 @@ def _pod_rows(pods):
     """The pod side of the sparse check: (valid bool[P], request limbs
     (hi, lo) int32[P,R] each, nonzero-dims bitmask int32[P,nw]). A dim the
     pod does not request non-zero holds int64 min, which exceeds nothing."""
-    from .pallas_check import _split_limbs
-
     nonzero = pods.req_present & (pods.req != 0)
     hi, lo = _split_limbs(jnp.where(nonzero, pods.req, _INT64_MIN))
     return pods.valid, hi, lo, _bitmask(nonzero)
@@ -293,8 +370,6 @@ def _classify_slots(table, valid, hi, lo, bits, cols):
     pod's own limbs against the gathered targets. Flag and bitmask tests
     are lane-masked reductions over the whole record, so no one-lane
     slice of it (a 128-lane tile per word) is materialized."""
-    from .pallas_check import _limb_gt
-
     P, K = cols.shape
     R, W = hi.shape[1], table.shape[1]
     o = 1 + bits.shape[1]
@@ -382,18 +457,6 @@ def _gather_statuses_blocked(state, pods, cols, on_equal, step3_on_equal):
         tuple(blocks(a) for a in rows) + (blocks(cols, -1),),
     )  # [nb, pb, K] int8
     return out.reshape(nb * pb, K)[:P]
-
-
-@partial(jax.jit, static_argnames=("on_equal", "step3_on_equal"))
-def check_pods_gather_statuses(
-    state: ThrottleState, pods: PodBatch, cols: jnp.ndarray,
-    on_equal: bool = False, step3_on_equal: bool = True,
-):
-    """``check_pods_gather`` returning the raw int8[P,K] per-slot statuses
-    instead of compact counts — the micro-batching pre_filter front-end
-    needs each pod's per-throttle classification to build reference reason
-    strings (plugin.go:182-214), not just the verdict."""
-    return _gather_statuses_blocked(state, pods, cols, on_equal, step3_on_equal)
 
 
 @partial(jax.jit, static_argnames=("on_equal", "step3_on_equal"))

@@ -247,7 +247,6 @@ class KubeThrottler:
         if self.device_manager is not None:
             self.health.register("device", self._device_health)
         self.health.register("workqueues", self._workqueue_health)
-        self._coalescer = None
         # interned-verdict cache (engine/verdictcache.py): pre_filter /
         # pre_filter_batch probe it before any plane walk. Requires the
         # device manager — the fingerprint reads its epoch planes.
@@ -303,17 +302,6 @@ class KubeThrottler:
             else "ok"
         )
         return state, depths
-
-    def coalescer(self, window_s: float = 0.0, max_batch: int = 64):
-        """The micro-batching pre_filter front-end for CONCURRENT callers:
-        one fused device dispatch per window instead of one per caller
-        (plugin/coalesce.py). First call constructs it; parameters are
-        fixed thereafter."""
-        if self._coalescer is None:
-            from .coalesce import PreFilterCoalescer
-
-            self._coalescer = PreFilterCoalescer(self, window_s, max_batch)
-        return self._coalescer
 
     # -------------------------------------------------------------- prefilter
 
@@ -373,9 +361,7 @@ class KubeThrottler:
         self, pod: Pod, thr4, clthr4, emit_events: bool = True
     ) -> Status:
         """Reason composition from both kinds' check_throttled 4-tuples —
-        ordering mirrors plugin.go:182-214 exactly. Shared by the direct
-        path and the micro-batching coalescer (which produces the tuples
-        from one fused dispatch)."""
+        ordering mirrors plugin.go:182-214 exactly."""
         thr_active, thr_insufficient, thr_exceeds, thr_affected = thr4
         clthr_active, clthr_insufficient, clthr_exceeds, clthr_affected = clthr4
 

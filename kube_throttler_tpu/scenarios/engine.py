@@ -6,13 +6,13 @@ Topology and trace derive deterministically from ``(scenario, seed)``
 mock apiserver over real HTTP → reflectors → adaptive micro-batched
 ingest → shared informers → both controllers → device planes → two-lane
 async status committer — exactly the production wiring (cli.py remote
-mode / bench.py's remote rung), with ONE seeded
+mode), with ONE seeded
 :class:`~kube_throttler_tpu.faults.plan.FaultPlan` shared by the server's
 fault verbs, the client transport, and the engine's own ``scenario.*``
 action sites (apiserver restart with RV-window reset, continue-token
 expiry, churn stalls, the injected regression, the leader-kill episode).
 
-Measurements reuse the bench anchors (scenarios/measure.py): flip lag is
+Measurements reuse the shared anchors (scenarios/measure.py): flip lag is
 crossing-anchored against each label group's running cpu sum, maintained
 from the trace's own ``prev_m`` chain so drain waves and herd bursts keep
 the sums exact. After the replay the engine QUIESCES (reflectors past the

@@ -1,14 +1,16 @@
-"""Shared measurement anchors for the scenario engine and bench.py.
+"""Shared measurement anchors for the scenario corpus, the host
+profilers in ``tools/`` and ``chip_smoke.py``.
 
-These were born in bench.py's serving rungs (PR 2/PR 5) and moved here so
-the scenario corpus and the bench ladder measure flip lag with ONE
-implementation — a per-scenario flip-p99 that silently anchored
-differently from the bench's would make the SLO gate incomparable with
-every BENCH_*.json on record. bench.py imports these under its historical
-underscore names.
+Every scenario measures flip lag with ONE implementation, so a
+per-scenario flip-p99 cannot silently anchor differently from another's.
+``served_state`` and ``build_served_stack`` build the served-stack state
+at a given scale.
 """
 
 from __future__ import annotations
+
+import sys
+import time
 
 from ..utils.lockorder import make_lock
 
@@ -196,7 +198,7 @@ def flip_band_mc(P: int, groups: int) -> int:
 
 
 def served_state(P: int, T: int, groups: int = 500, seed: int = 0):
-    """The served-stack state bench.py and chip_smoke.py load: T
+    """The served-stack state ``build_served_stack`` and chip_smoke.py load: T
     ``served_throttle`` objects (flip band on) and P bound Running pods,
     each in group g{0..groups-1} with a 100m-700m cpu request, drawn
     from ``random.Random(seed)``. Returns (throttles, pods)."""
@@ -218,3 +220,56 @@ def served_state(P: int, T: int, groups: int = 500, seed: int = 0):
         for i in range(P)
     ]
     return throttles, pods
+
+
+def build_served_stack(P: int, T: int, groups: int = 500, label: str = "served"):
+    """The real daemon stack at scale: store events → device mirror →
+    controllers → statuses, over ``served_state(P, T, groups)``. Returns
+    (store, plugin). Each set-up phase's cost is logged to stderr (it is
+    the cost of cold-starting this state)."""
+    from ..api.pod import Namespace
+    from ..engine.store import Store
+    from ..plugin import KubeThrottler, decode_plugin_args
+    from ..utils.gchygiene import freeze_startup_heap
+
+    def log(msg: str) -> None:
+        print(f"[{label}] {msg}", file=sys.stderr, flush=True)
+
+    store = Store()
+    plugin = KubeThrottler(
+        decode_plugin_args(
+            {"name": "kube-throttler", "targetSchedulerName": "my-scheduler"}
+        ),
+        store,
+        use_device=True,
+    )
+    store.create_namespace(Namespace("default"))
+    throttles, pods = served_state(P, T, groups)
+
+    t0 = time.perf_counter()
+    for thr in throttles:
+        store.create_throttle(thr)
+    log(f"created {T} throttles in {time.perf_counter() - t0:.1f}s "
+        f"(flip band: every 24th cpu threshold at {flip_band_mc(P, groups)}m)")
+
+    t0 = time.perf_counter()
+    for pod in pods:
+        store.create_pod(pod)
+    t_pods = time.perf_counter() - t0
+    log(f"created {P} bound pods in {t_pods:.1f}s "
+        f"({t_pods / P * 1e6:.0f}us/event through the live index)")
+
+    t0 = time.perf_counter()
+    n = plugin.run_pending_once()
+    log(f"initial reconcile of {n} keys in {time.perf_counter() - t0:.1f}s")
+
+    if plugin.device_manager is not None:
+        t0 = time.perf_counter()
+        nk = len(plugin.device_manager.prewarm())
+        log(f"prewarmed {nk} kernel shapes in {time.perf_counter() - t0:.1f}s")
+    # the daemon's own pre-serving step (cli.py): freeze the startup heap
+    # so automatic full GCs never rescan the pod graph
+    frozen = freeze_startup_heap()
+    if frozen > 0:
+        log(f"gc hygiene: froze {frozen} startup objects; gen2 deferred")
+    return store, plugin

@@ -1,8 +1,8 @@
 """Shared-nothing multiprocess keyspace sharding (ROADMAP item 1).
 
-Measured thread scaling of the single-process engine is ~1.05 at 1→4
-threads (BENCH_PR5/PR2): the GIL, not the kernels, caps decisions/s and
-ingest/s per host. This package cuts the control plane along PAPER.md's
+Thread scaling of the single-process engine measured ~1.05 at 1→4
+threads on CPU: the GIL, not the kernels, caps decisions/s and ingest/s
+per host. This package cuts the control plane along PAPER.md's
 layer 4-5 controller/plugin seam into N worker *processes*:
 
 - :mod:`ring` — consistent-hash partitioning of the Throttle /

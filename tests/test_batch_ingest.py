@@ -16,6 +16,7 @@ from __future__ import annotations
 import queue
 from dataclasses import replace
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -30,10 +31,10 @@ from kube_throttler_tpu.api.types import (
     ThrottleSpec,
 )
 from kube_throttler_tpu.client.watch import Watch
-from kube_throttler_tpu.engine import devicestate as ds_mod
 from kube_throttler_tpu.engine.ingest import MicroBatchIngest
 from kube_throttler_tpu.engine.store import Event, EventType, Store
 from kube_throttler_tpu.faults.plan import FaultPlan
+from kube_throttler_tpu.ops.aggregate import apply_pod_deltas_batched
 from kube_throttler_tpu.plugin import KubeThrottler, decode_plugin_args
 
 
@@ -283,7 +284,8 @@ class TestPropertyEquivalence:
 class TestPendingDeltaKernelParity:
     def test_host_route_matches_device_kernel(self):
         """apply_pending_batched's host mirror is bit-identical to the real
-        apply_pod_deltas_batched kernel over the same encoded burst."""
+        apply_pod_deltas_batched kernel, called directly over the same
+        encoded burst."""
         rng = np.random.default_rng(7)
         store, plugin = _build()
         ks = plugin.device_manager.throttle
@@ -301,20 +303,21 @@ class TestPendingDeltaKernelParity:
         base_req = rng.integers(0, 10**10, size=(ks.tcap, ks.R)).astype(np.int64)
         base_ctb = rng.integers(0, 20, size=(ks.tcap, ks.R)).astype(np.int32)
 
-        def run(device: bool):
-            old = ds_mod._AGG_DEVICE_DELTAS
-            ds_mod._AGG_DEVICE_DELTAS = device
-            try:
-                ks.agg_cnt = base_cnt.copy()
-                ks.agg_req = base_req.copy()
-                ks.agg_contrib = base_ctb.copy()
-                ks.apply_pending_batched(list(pending))
-                return ks.agg_cnt.copy(), ks.agg_req.copy(), ks.agg_contrib.copy()
-            finally:
-                ds_mod._AGG_DEVICE_DELTAS = old
+        ks.agg_cnt = base_cnt.copy()
+        ks.agg_req = base_req.copy()
+        ks.agg_contrib = base_ctb.copy()
+        ks.apply_pending_batched(list(pending))
+        h_cnt, h_req, h_ctb = ks.agg_cnt, ks.agg_req, ks.agg_contrib
 
-        h_cnt, h_req, h_ctb = run(False)
-        d_cnt, d_req, d_ctb = run(True)
+        ids, sign, req, pres = ks._pending_batch_arrays(list(pending))
+        d_cnt, d_req, d_ctb = (
+            np.asarray(a)
+            for a in apply_pod_deltas_batched(
+                jnp.asarray(base_cnt), jnp.asarray(base_req), jnp.asarray(base_ctb),
+                jnp.asarray(ids), jnp.asarray(sign),
+                jnp.asarray(req), jnp.asarray(pres),
+            )
+        )
         np.testing.assert_array_equal(h_cnt, d_cnt)
         np.testing.assert_array_equal(h_req, d_req)
         np.testing.assert_array_equal(h_ctb, d_ctb)

@@ -5,6 +5,7 @@ and equality-boundary edge cases."""
 import random
 from datetime import datetime, timezone
 
+import jax
 import numpy as np
 import pytest
 
@@ -18,6 +19,7 @@ from kube_throttler_tpu.api import (
 )
 from kube_throttler_tpu.api.pod import make_pod
 from kube_throttler_tpu.api.types import CalculatedThreshold, ThrottleStatus
+from kube_throttler_tpu.engine import devicestate as ds
 from kube_throttler_tpu.ops import (
     CHECK_NOT_AFFECTED,
     STATUS_NAMES,
@@ -98,6 +100,34 @@ def _build_objects(rng, n_throttles, n_pods, kind):
                 reqs[r] = rng.choice(BOUNDARY_VALUES)
         pods.append(make_pod(f"p{i}", requests=reqs))
     return throttles, reserved, pods
+
+
+class _StagingPlanes:
+    """A ThrottleState's planes under the staging names that check_pod's
+    host tiers read from a kind's state."""
+
+    def __init__(self, state):
+        for field, attr in ds._KindState._THR_FIELDS:
+            setattr(self, attr, np.ascontiguousarray(np.asarray(getattr(state, field))))
+        self.R = self.thr_req.shape[1]
+        self._cls_cache = self._cls_scratch = None
+
+
+def _host_tier_statuses(state, cols, pod_req, pod_present, on_equal, step3):
+    """{tier: int8[K]} statuses of one pod against the state rows ``cols``
+    from each host tier of check_pod: numpy always, native C++ where the
+    library loads."""
+    planes = _StagingPlanes(state)
+    pod_req = np.ascontiguousarray(pod_req, dtype=np.int64)
+    pod_present = np.ascontiguousarray(pod_present, dtype=bool)
+    rows = ds.DeviceStateManager._gather_check_rows(planes, cols)
+    out = {"numpy": ds._host_classify_rows(rows, pod_req, pod_present, on_equal, step3)}
+    lib = ds._native_cls_lib()
+    if lib is not None:
+        out["native"] = ds._native_classify_cols(
+            lib, planes, cols, pod_req, pod_present, on_equal, step3
+        )
+    return out
 
 
 @pytest.mark.parametrize("kind", ["throttle", "clusterthrottle"])
@@ -260,8 +290,8 @@ def _assert_gather_is_dense(state, batch, cols, on_equal, step3):
     """The sparse statuses are the dense [P,T] check_pods read through
     the cols (padded slots NOT_AFFECTED), and the compact outputs follow."""
     from kube_throttler_tpu.ops.check import (
+        _gather_statuses_blocked,
         check_pods_gather,
-        check_pods_gather_statuses,
         statuses_to_compact,
     )
 
@@ -270,8 +300,8 @@ def _assert_gather_is_dense(state, batch, cols, on_equal, step3):
                                   on_equal=on_equal, step3_on_equal=step3))
     want = np.where(cols >= 0, np.take_along_axis(dense, np.maximum(cols, 0), axis=1),
                     CHECK_NOT_AFFECTED)
-    got = np.asarray(check_pods_gather_statuses(
-        state, batch, cols, on_equal=on_equal, step3_on_equal=step3))
+    statuses = jax.jit(_gather_statuses_blocked, static_argnums=(3, 4))
+    got = np.asarray(statuses(state, batch, cols, on_equal, step3))
     np.testing.assert_array_equal(got, want)
     want_counts, want_ok = statuses_to_compact(want)
     got_counts, got_ok = check_pods_gather(
@@ -413,7 +443,6 @@ def test_native_handle_lifecycle():
     two paths cannot double-free."""
     import gc
 
-    from kube_throttler_tpu.engine import devicestate as ds
     from kube_throttler_tpu.ops.schema import DimRegistry
 
     lib = ds._native_cls_lib()
@@ -436,12 +465,10 @@ def test_native_handle_lifecycle():
     assert not fin2.alive, "GC must retire the live handle"
 
 
-def test_host_single_check_matches_device_kernel():
-    """check_pod's default HOST numpy classifier (_host_classify_rows) must
-    agree cell-for-cell with the device kernel path
-    (KT_SINGLE_CHECK_DEVICE=1) on randomized live state — the two are
-    line-for-line ports of the same 4-step resolution and this pins them
-    together."""
+def _live_stack():
+    """A served stack with randomized live state (40 throttles over 5
+    label groups, 120 bound pods), and 24 probe pods not in the store.
+    Returns (plugin, probes)."""
     import random
     from dataclasses import replace
 
@@ -503,12 +530,6 @@ def test_host_single_check_matches_device_kernel():
         p.status.phase = "Running"
         store.create_pod(p)
     plugin.run_pending_once()
-
-    dm = plugin.device_manager
-    # the test pins BOTH implementations against each other explicitly by
-    # forcing the route per-iteration (the ambient resolution — kernel on
-    # cpu, host on accelerators, KT_SINGLE_CHECK_DEVICE override — is not
-    # under test here)
     probes = [
         make_pod(
             f"q{i}",
@@ -518,22 +539,33 @@ def test_host_single_check_matches_device_kernel():
         )
         for i in range(24)
     ]
+    return plugin, probes
+
+
+def test_host_single_check_matches_device_kernel():
+    """check_pod's HOST route (indexed_check_max affected throttles or
+    fewer) must agree cell-for-cell with its dense device fallback
+    (``check_pods`` over the pod's mask row, forced by indexed_check_max
+    = 0) on randomized live state — the two are line-for-line ports of
+    the same 4-step resolution and this pins them together."""
+    plugin, probes = _live_stack()
+    dm = plugin.device_manager
+    # the test pins BOTH routes against each other explicitly by setting
+    # the route threshold per iteration
     for on_equal in (False, True):
         for kind in ("throttle", "clusterthrottle"):
             for p in probes:
-                dm._single_check_device = False
+                dm.indexed_check_max = 1024
                 host = dm.check_pod(p, kind, on_equal)
-                dm._single_check_device = True
+                dm.indexed_check_max = 0
                 dev = dm.check_pod(p, kind, on_equal)
                 assert host == dev, (kind, on_equal, p.name, host, dev)
+    dm.indexed_check_max = 1024
 
     # the host route has two tiers (native C++ ktn_cls_run when the lib
     # loads, numpy _host_classify_rows otherwise); pin them against each
     # other too by forcing the numpy tier via the module-level lib cache
-    from kube_throttler_tpu.engine import devicestate as ds
-
     if ds._native_cls_lib() is not None:
-        dm._single_check_device = False
         native_res = [
             dm.check_pod(p, k, oe)
             for oe in (False, True)
@@ -552,3 +584,55 @@ def test_host_single_check_matches_device_kernel():
         finally:
             ds._cls_lib, ds._cls_lib_tried = old
         assert native_res == numpy_res
+
+
+def test_limb_compare_extremes():
+    """Limb-split compares must hold at int64 extremes and negatives."""
+    import jax.numpy as jnp
+
+    from kube_throttler_tpu.ops.check import _limb_gt, _split_limbs
+
+    vals = np.array(
+        [0, 1, -1, 2**31, -(2**31), 2**32, -(2**32), 2**62, -(2**62),
+         2**63 - 1, -(2**63), 123456789012345, -987654321098765],
+        dtype=np.int64,
+    )
+    a = jnp.asarray(vals)[:, None]
+    b = jnp.asarray(vals)[None, :]
+    a_hi, a_lo = _split_limbs(a)
+    b_hi, b_lo = _split_limbs(b)
+    np.testing.assert_array_equal(np.asarray(_limb_gt(a_hi, a_lo, b_hi, b_lo)), vals[:, None] > vals[None, :])
+
+
+def test_check_pod_host_route_dispatches_nothing(monkeypatch):
+    """Up to indexed_check_max affected throttles a single-pod check is
+    host arithmetic only: no device kernel runs, on either host tier."""
+    plugin, probes = _live_stack()
+    dm = plugin.device_manager
+
+    def no_dispatch(*a, **k):
+        raise AssertionError("check_pod dispatched a device kernel")
+
+    for name in ("check_pods", "check_pods_compact", "check_pods_gather"):
+        monkeypatch.setattr(ds, name, no_dispatch)
+    results = [dm.check_pod(p, "throttle") for p in probes]
+    assert any(results)
+    monkeypatch.setattr(ds, "_cls_lib", None)
+    monkeypatch.setattr(ds, "_cls_lib_tried", True)
+    assert [dm.check_pod(p, "throttle") for p in probes] == results
+
+
+def test_prewarm_warms_only_the_gather_rungs():
+    """prewarm dispatches the sparse [P,K] batch kernel once a rung a
+    kind, and nothing else. (The empty clusterthrottle kind's capacity
+    holds no sparse rung.)"""
+    plugin, _ = _live_stack()
+    dm = plugin.device_manager
+    shapes = dm.prewarm()
+    assert any(s.startswith("throttle:") for s in shapes), shapes
+    for kind in ("throttle", "clusterthrottle"):
+        ks = dm._kind(kind)
+        for label in (s for s in shapes if s.startswith(f"{kind}:")):
+            assert label.startswith(f"{kind}:check_pods_gather[{ks.pcap},"), label
+    assert all(s.split(":")[0] in ("throttle", "clusterthrottle") for s in shapes)
+    assert len(shapes) == len(set(shapes))

@@ -347,163 +347,3 @@ class TestConcurrentCheck:
         # aggregate flushes — the regression this guards — sits under 0.1x.
         # The generous bar keeps the test deterministic under suite load.
         assert under_churn > idle * 0.2, (idle, under_churn)
-
-
-class TestPreFilterCoalescer:
-    """The micro-batching front-end must be semantically invisible:
-    identical Status (code + reason tuple) to the direct pre_filter for
-    every pod, under real concurrency (plugin/coalesce.py)."""
-
-    def _stack(self, n_thr=24, n_pods=60, groups=6):
-        import random
-
-        from kube_throttler_tpu.api.pod import Namespace, make_pod
-        from kube_throttler_tpu.api.types import (
-            LabelSelector,
-            ResourceAmount,
-            Throttle,
-            ThrottleSelector,
-            ThrottleSelectorTerm,
-            ThrottleSpec,
-        )
-        from kube_throttler_tpu.engine.store import Store
-        from kube_throttler_tpu.plugin import KubeThrottler, decode_plugin_args
-
-        rng = random.Random(11)
-        store = Store()
-        store.create_namespace(Namespace("default"))
-        plugin = KubeThrottler(
-            decode_plugin_args(
-                {"name": "kube-throttler", "targetSchedulerName": "my-scheduler"}
-            ),
-            store,
-            use_device=True,
-        )
-        for i in range(n_thr):
-            store.create_throttle(
-                Throttle(
-                    name=f"t{i}",
-                    namespace="default",
-                    spec=ThrottleSpec(
-                        throttler_name="kube-throttler",
-                        threshold=ResourceAmount.of(
-                            pod=rng.choice([None, 1, 3]),
-                            requests={"cpu": f"{rng.randrange(1, 9) * 100}m"},
-                        ),
-                        selector=ThrottleSelector(
-                            selector_terms=(
-                                ThrottleSelectorTerm(
-                                    LabelSelector(
-                                        match_labels={"grp": f"g{i % groups}"}
-                                    )
-                                ),
-                            )
-                        ),
-                    ),
-                )
-            )
-        from dataclasses import replace
-
-        for i in range(n_pods):
-            p = make_pod(
-                f"p{i}",
-                namespace="default",
-                labels={"grp": f"g{rng.randrange(groups)}"},
-                requests={"cpu": f"{rng.randrange(1, 6) * 100}m"},
-            )
-            p = replace(p, spec=replace(p.spec, node_name="n1"))
-            p.status.phase = "Running"
-            store.create_pod(p)
-        plugin.run_pending_once()
-        return store, plugin, rng
-
-    def _probes(self, rng, n, groups=6):
-        from kube_throttler_tpu.api.pod import make_pod
-
-        return [
-            make_pod(
-                f"probe{i}",
-                namespace="default",
-                labels={"grp": f"g{i % groups}"},
-                requests={"cpu": f"{rng.randrange(1, 9) * 100}m"},
-            )
-            for i in range(n)
-        ]
-
-    def test_check_pods_multi_matches_check_pod(self):
-        """Both routes of the multi check pinned against check_pod: the
-        HOST route (native B sub-µs passes — the default) and the fused
-        DEVICE dispatch (forced; the remote-accelerator A/B side)."""
-        import os
-
-        from kube_throttler_tpu.engine import devicestate as ds
-
-        _, plugin, rng = self._stack()
-        dm = plugin.device_manager
-        probes = self._probes(rng, 13)
-        # the False leg is the NATIVE host route only when the lib loaded;
-        # a silent load failure would run the device path twice and the
-        # native multi decode would lose coverage — so demand the lib
-        # unless the numpy tier was explicitly requested
-        native_available = ds._native_cls_lib() is not None
-        assert native_available or os.environ.get("KT_TPU_NO_NATIVE") == "1", (
-            "native lib failed to load — the host-route leg would not "
-            "exercise the native multi path (run with a C++ toolchain)"
-        )
-        legs = ([False] if native_available else []) + [True]
-        for forced_device in legs:
-            dm._single_check_device = forced_device
-            for kind in ("throttle", "clusterthrottle"):
-                multi = dm.check_pods_multi(probes, kind)
-                for pod, got in zip(probes, multi):
-                    assert got == dm.check_pod(pod, kind), (
-                        forced_device, kind, pod.name,
-                    )
-        # and the numpy host tier (no native lib) through the same surface
-        old = (ds._cls_lib, ds._cls_lib_tried)
-        ds._cls_lib, ds._cls_lib_tried = None, True
-        try:
-            dm._single_check_device = False
-            for kind in ("throttle", "clusterthrottle"):
-                multi = dm.check_pods_multi(probes, kind)
-                for pod, got in zip(probes, multi):
-                    assert got == dm.check_pod(pod, kind), ("numpy", kind, pod.name)
-        finally:
-            ds._cls_lib, ds._cls_lib_tried = old
-
-    def test_coalesced_matches_direct_concurrent(self):
-        import threading
-
-        _, plugin, rng = self._stack()
-        co = plugin.coalescer(window_s=2e-3)
-        probes = self._probes(rng, 32)
-        want = {p.name: plugin.pre_filter(p) for p in probes}
-
-        got = {}
-        lock = threading.Lock()
-        barrier = threading.Barrier(8)
-
-        def worker(idx):
-            barrier.wait()
-            for p in probes[idx::8]:
-                s = co.pre_filter(p)
-                with lock:
-                    got[p.name] = s
-
-        threads = [threading.Thread(target=worker, args=(i,)) for i in range(8)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(20)
-        assert len(got) == len(probes)
-        for name, status in want.items():
-            assert got[name].code == status.code, name
-            assert got[name].reasons == status.reasons, name
-
-    def test_coalesced_single_caller(self):
-        _, plugin, rng = self._stack()
-        co = plugin.coalescer()
-        for p in self._probes(rng, 6):
-            direct = plugin.pre_filter(p)
-            coal = co.pre_filter(p)
-            assert coal.code == direct.code and coal.reasons == direct.reasons

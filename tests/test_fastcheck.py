@@ -1,4 +1,5 @@
-"""Residual-form fast check must be bit-identical to the direct kernel."""
+"""check_pod's single-pod fast path, the host tiers, must be bit-identical
+to the direct dense kernel on the pod's matched rows."""
 
 import random
 
@@ -6,14 +7,8 @@ import numpy as np
 import pytest
 
 from kube_throttler_tpu.ops import DimRegistry, check_pods, encode_pods, encode_throttle_state
-from kube_throttler_tpu.ops.fastcheck import (
-    fast_check_pods,
-    fast_check_pods_compact,
-    precompute_check_state,
-)
-from kube_throttler_tpu.ops.check import check_pods_compact
 
-from tests.test_check_kernel import _build_objects
+from tests.test_check_kernel import _build_objects, _host_tier_statuses
 
 
 @pytest.mark.parametrize("kind", ["throttle", "clusterthrottle"])
@@ -31,11 +26,9 @@ def test_fast_matches_direct(kind, on_equal, seed):
     step3 = True if kind == "throttle" else on_equal
 
     direct = np.asarray(check_pods(state, batch, mask, on_equal=on_equal, step3_on_equal=step3))
-    pre = precompute_check_state(state)
-    fast = np.asarray(fast_check_pods(pre, batch, mask, on_equal=on_equal, step3_on_equal=step3))
-    np.testing.assert_array_equal(fast, direct)
-
-    dc, ds = check_pods_compact(state, batch, mask, on_equal=on_equal, step3_on_equal=step3)
-    fc, fs = fast_check_pods_compact(pre, batch, mask, on_equal=on_equal, step3_on_equal=step3)
-    np.testing.assert_array_equal(np.asarray(fc), np.asarray(dc))
-    np.testing.assert_array_equal(np.asarray(fs), np.asarray(ds))
+    req, present = np.asarray(batch.req), np.asarray(batch.req_present)
+    for i in range(len(pods)):
+        cols = np.nonzero(mask[i])[0]
+        tiers = _host_tier_statuses(state, cols, req[i], present[i], on_equal, step3)
+        for tier, got in tiers.items():
+            np.testing.assert_array_equal(got, direct[i, cols], err_msg=f"{tier} pod {i}")

@@ -1,28 +1,22 @@
-"""Property tests for the two hot-path kernels added for the bench push:
+"""Property tests for two hot paths:
 
-- ``fast_check_pod_indexed`` must agree with the dense residual-form check
-  restricted to the gathered rows, for every (on_equal, step3_on_equal)
-  variant and under index padding;
+- check_pod's host single-pod tiers must agree with the dense check
+  restricted to the pod's matched rows, for every (on_equal,
+  step3_on_equal) variant, invalid rows and repeated rows included;
 - ``apply_pod_deltas_batched`` must equal sequential ``apply_pod_delta``
   application (scatter-adds commute exactly in int64).
 """
 
 import random
 
-import jax
 import numpy as np
 import pytest
 
 from kube_throttler_tpu.ops.aggregate import apply_pod_delta, apply_pod_deltas_batched
-from kube_throttler_tpu.ops.check import CHECK_NOT_AFFECTED
-from kube_throttler_tpu.ops.fastcheck import (
-    fast_check_pod_indexed,
-    fast_check_pod_packed,
-    fast_check_pods,
-    pack_check_state,
-    precompute_check_state,
-)
+from kube_throttler_tpu.ops.check import check_pods
 from kube_throttler_tpu.ops.schema import PodBatch, ThrottleState
+
+from tests.test_check_kernel import _host_tier_statuses
 
 
 def _rand_state(npr, T, R):
@@ -67,38 +61,19 @@ def test_indexed_matches_dense(on_equal, s3):
     for trial in range(20):
         T, R, K = 37, 5, 8
         state = _rand_state(npr, T, R)
-        pre = precompute_check_state(state)
         pod_req, pod_present = _rand_pod(rng, R)
-
-        # K slots: some live rows, some padded (idx_valid=False, idx clamped 0)
-        n_live = rng.randrange(0, K + 1)
-        idx = np.zeros(K, dtype=np.int32)
-        valid = np.zeros(K, dtype=bool)
-        idx[:n_live] = npr.integers(0, T, n_live)
-        valid[:n_live] = True
-
-        got = np.asarray(
-            fast_check_pod_indexed(pre, pod_req, pod_present, idx, valid, on_equal, s3)
-        )
-        packed = np.asarray(
-            fast_check_pod_packed(
-                pack_check_state(pre), pod_req, pod_present, idx, valid, on_equal, s3
-            )
-        )
-        np.testing.assert_array_equal(packed, got)
+        # up to K matched rows, repeats allowed
+        cols = npr.integers(0, T, rng.randrange(0, K + 1))
 
         batch = PodBatch(
             valid=np.ones(1, dtype=bool), req=pod_req[None], req_present=pod_present[None]
         )
-        mask = np.zeros((1, T), dtype=bool)
-        mask[0, idx[:n_live]] = True
-        dense = np.asarray(fast_check_pods(pre, batch, mask, on_equal, s3))[0]
-
-        for slot in range(K):
-            if valid[slot]:
-                assert got[slot] == dense[idx[slot]], (trial, slot)
-            else:
-                assert got[slot] == CHECK_NOT_AFFECTED
+        dense = np.asarray(
+            check_pods(state, batch, np.ones((1, T), dtype=bool), on_equal, s3)
+        )[0]
+        tiers = _host_tier_statuses(state, cols, pod_req, pod_present, on_equal, s3)
+        for tier, got in tiers.items():
+            np.testing.assert_array_equal(got, dense[cols], err_msg=f"{tier} trial {trial}")
 
 
 def test_batched_deltas_match_sequential():

@@ -12,8 +12,8 @@ Covers the stack bottom-up:
 - controller commit ordering (a flipping key's status write dispatches
   before the refresh keys drained in the same batch, regardless of
   enqueue order);
-- the two ADVICE r5 regressions: mid-batch R growth in check_pods_multi,
-  and the KT_GATHER_CHUNK_ELEMS import-time env parse.
+- check_pod across R growth (a never-seen resource name), and the
+  KT_GATHER_CHUNK_ELEMS import-time env parse.
 """
 
 from __future__ import annotations
@@ -468,47 +468,49 @@ class TestRemoteFlipUnderFaults:
 # ---------------------------------------------------------------------------
 
 
-class TestCheckPodsMultiRGrowth:
-    def _grown_batch(self):
+class TestCheckPodRGrowth:
+    def _grown_stack(self):
         store, plugin = _stack()
         store.create_throttle(_throttle("t1", {"grp": "a"}, requests={"cpu": "1"}))
         store.create_pod(_bound("p0", {"grp": "a"}, cpu="900m"))
         plugin.run_pending_once()
-        # probe pods NOT in the store; the second introduces a never-seen
-        # resource name mid-batch, growing ks.R after p-first was encoded
+        # probe pods NOT in the store; the grower introduces more
+        # never-seen resource names than the dim capacity holds, growing
+        # the kind's R when it is encoded
         first = make_pod("probe-a", labels={"grp": "a"}, requests={"cpu": "200m"})
+        capacity = plugin.device_manager.dims.capacity
+        widgets = {f"vendor.example/widget-{i}": "3" for i in range(capacity)}
         grower = make_pod(
-            "probe-b",
-            labels={"grp": "a"},
-            requests={"cpu": "200m", "vendor.example/widget": "3"},
+            "probe-b", labels={"grp": "a"}, requests={"cpu": "200m", **widgets}
         )
-        third = make_pod("probe-c", labels={"grp": "a"}, requests={"cpu": "200m"})
-        return plugin, [first, grower, third]
+        return store, plugin, first, grower
 
-    def test_host_route_matches_single_pod_checks(self):
-        plugin, pods = self._grown_batch()
+    def test_never_seen_resource_gets_reference_verdict(self):
+        store, plugin, _, grower = self._grown_stack()
         dm = plugin.device_manager
-        multi = dm.check_pods_multi(pods, "throttle")
-        # fresh equivalent objects so the per-pod path re-encodes at the
-        # grown R rather than hitting the batch's memo entries
-        import copy
+        R = dm.throttle.R
+        got = dm.check_pod(grower, "throttle")
+        assert dm.throttle.R > R
+        want = store.get_throttle("default", "t1").check_throttled_for(
+            grower, ResourceAmount(), False
+        )
+        # 0.9 + 0.2 ≥ 1 cpu ⇒ insufficient
+        assert want == "insufficient"
+        assert got == {"default/t1": want}
 
-        singles = [dm.check_pod(copy.deepcopy(p), "throttle") for p in pods]
-        assert multi == singles
-        # every verdict present: 0.9 + 0.2 ≥ 1 cpu ⇒ insufficient for all
-        for res in multi:
-            assert res == {"default/t1": "insufficient"}
-
-    def test_device_route_survives_mid_batch_growth(self, monkeypatch):
-        # the fused-kernel route previously crashed on the row-width
-        # mismatch (req[i] = rq[0] broadcast error); with the re-encode it
-        # must return the same verdicts as the host route
-        plugin, pods = self._grown_batch()
+    def test_encode_memo_reencodes_after_r_growth(self):
+        _, plugin, first, grower = self._grown_stack()
         dm = plugin.device_manager
-        monkeypatch.setattr(dm, "_single_check_device", True)
-        multi = dm.check_pods_multi(pods, "throttle")
-        for res in multi:
-            assert res == {"default/t1": "insufficient"}
+        ks = dm.throttle
+        assert dm.check_pod(first, "throttle") == {"default/t1": "insufficient"}
+        R = dm._encode_cache[id(first)][1]
+        dm.check_pod(grower, "throttle")
+        assert ks.R > R
+        # the memo entry holds the old width; the next check of the same
+        # object must re-encode at the grown R, not read past its row
+        assert dm.check_pod(first, "throttle") == {"default/t1": "insufficient"}
+        entry = dm._encode_cache[id(first)]
+        assert entry[1] == ks.R and entry[2].shape == (1, ks.R)
 
 
 class TestGcHygiene:

@@ -96,49 +96,36 @@ def test_check_pods_gather_compiles(one_chip, k):
     _fits(compiled)
 
 
-def test_fast_check_pod_packed_compiles(one_chip):
-    """The single-pod device check at the top prewarm ladder rung."""
-    from kube_throttler_tpu.engine.devicestate import CHECK_LADDER_MAX
-    from kube_throttler_tpu.ops.fastcheck import (
-        fast_check_pod_packed,
-        pack_check_state,
-        precompute_check_state,
-    )
+def test_check_pods_compiles(one_chip):
+    """check_pod's dense fallback above indexed_check_max: one pod row
+    against every throttle column."""
+    from kube_throttler_tpu.ops.check import check_pods
 
-    state, _ = _small()
-    packed = jax.eval_shape(
-        lambda s: pack_check_state(precompute_check_state(s)), _widen(state, TCAP, None)
-    )
-    packed = jax.tree.map(lambda a: _shape(a.shape, a.dtype, one_chip), packed)
-    R = state.thr_req.shape[1]
-    compiled = fast_check_pod_packed.lower(
-        packed,
-        _shape((R,), jnp.int64, one_chip),
-        _shape((R,), jnp.bool_, one_chip),
-        _shape((CHECK_LADDER_MAX,), jnp.int32, one_chip),
-        _shape((CHECK_LADDER_MAX,), jnp.bool_, one_chip),
-        False,
-        True,
-    ).compile()
-    _fits(compiled)
-
-
-def test_pallas_check_pods_compiles(one_chip):
-    """The Mosaic sweep at 100352 × 10240 (block multiples of 100k × 10k)."""
-    from kube_throttler_tpu.ops.fastcheck import precompute_check_state
-    from kube_throttler_tpu.ops.pallas_check import pallas_check_pods
-
-    Pn, Tn = 100352, 10240
     state, pods = _small()
-    pre = jax.eval_shape(precompute_check_state, _widen(state, Tn, None))
-    pre = jax.tree.map(lambda a: _shape(a.shape, a.dtype, one_chip), pre)
-    compiled = pallas_check_pods.lower(
-        pre,
-        _widen(pods, Pn, one_chip),
-        _shape((Pn, Tn), jnp.bool_, one_chip),
+    compiled = check_pods.lower(
+        _widen(state, TCAP, one_chip),
+        _widen(pods, 1, one_chip),
+        _shape((1, TCAP), jnp.bool_, one_chip),
+        on_equal=False,
+        step3_on_equal=True,
     ).compile()
     _fits(compiled)
-    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_check_pods_compact_compiles(one_chip):
+    """The dense batch check, taken when the sparse [P,K] companion is not
+    the better shape, over the whole [P,T] mask."""
+    from kube_throttler_tpu.ops.check import check_pods_compact
+
+    state, pods = _small()
+    compiled = check_pods_compact.lower(
+        _widen(state, TCAP, one_chip),
+        _widen(pods, PCAP, one_chip),
+        _shape((PCAP, TCAP), jnp.bool_, one_chip),
+        on_equal=False,
+        step3_on_equal=True,
+    ).compile()
+    _fits(compiled)
 
 
 @pytest.mark.parametrize("mesh_shape", [(2, 2), (4, 1)])

@@ -10,12 +10,12 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-import bench  # noqa: E402
+from kube_throttler_tpu.scenarios.measure import build_served_stack  # noqa: E402
 
 P = int(sys.argv[1]) if len(sys.argv) > 1 else 10_000
 T = int(sys.argv[2]) if len(sys.argv) > 2 else 1_000
 
-store, plugin = bench.build_served_stack(P, T, label="prof")
+store, plugin = build_served_stack(P, T, label="prof")
 
 plugin.pre_filter_batch()  # warm/compile
 

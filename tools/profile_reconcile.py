@@ -14,14 +14,14 @@ from dataclasses import replace as dc_replace
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-import bench  # noqa: E402
+from kube_throttler_tpu.scenarios.measure import build_served_stack  # noqa: E402
 from kube_throttler_tpu.api.pod import make_pod  # noqa: E402
 
 P = int(sys.argv[1]) if len(sys.argv) > 1 else 2_000
 T = int(sys.argv[2]) if len(sys.argv) > 2 else 200
 EVENTS = int(sys.argv[3]) if len(sys.argv) > 3 else 2_000
 
-store, plugin = bench.build_served_stack(P, T, label="prof")
+store, plugin = build_served_stack(P, T, label="prof")
 
 rng = random.Random(1)
 pods = store.list_pods()
