@@ -27,7 +27,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dataclasses import fields as _dc_fields
@@ -130,6 +130,71 @@ def full_update_step(
     return counts, schedulable, used_cnt, used_req, st_cnt, st_req
 
 
+# Slots per row of the segmented sums: one vector register's lanes.
+_LANES = 128
+
+
+def _limbs(x: jnp.ndarray) -> jnp.ndarray:
+    """int64[P,R] → int32[P,3R]: bits 0-15, bits 16-31 (both ≥ 0) and the
+    signed high word. Lane sums of the 16-bit limbs cannot overflow int32;
+    the high word's wrap only drops multiples of 2^64 once it is shifted
+    into place, so ``_unlimb`` of the limb sums is the int64 sum."""
+    return jnp.concatenate([x & 0xFFFF, (x >> 16) & 0xFFFF, x >> 32], axis=1).astype(jnp.int32)
+
+
+def _unlimb(s: jnp.ndarray) -> jnp.ndarray:
+    """int32 sums [3R, …] of ``_limbs`` columns → int64 sums [R, …]."""
+    a, b, h = (v.astype(jnp.int64) for v in jnp.split(s, 3))
+    return a + (b << 16) + (h << 32)
+
+
+def _used_by_target(pods: PodBatch, tgt: jnp.ndarray, T: int):
+    """(used_cnt int64[T], used_req int64[T,R], contrib int32[T,R]) of
+    the flat [P·K] slots whose throttle is ``tgt`` (T: the slot counts
+    nowhere), as sums over segments of the slots sorted by target.
+
+    One sort orders the slots by target, each carrying its pod, and a
+    binary search finds each target's segment [b[t], b[t+1]) of them, so
+    its count is b[t+1] − b[t]. One row gather reads each sorted slot's
+    pod record (``_limbs`` of its requests, its presence bits), turned
+    so the slots ride the lanes and cut into rows of _LANES slots. A
+    target's sum is S(b[t+1]) − S(b[t]), where S(b), the sum of the
+    slots before b, is the int64 prefix sum of the whole rows before
+    b's row plus a masked sum of b's own row. Lane sums stay in int32
+    limbs; only the [R, rows] and [R, T] sums are int64. Slots at T sort
+    past b[T] and reach no segment. Integer adds wrap, so each
+    difference is the segment's sum modulo 2^64 (2^32 for the int32
+    presence counts): bit for bit what scatter-adding the slots gives,
+    overflow included. Nothing is kept between calls."""
+    P_, R = pods.req.shape
+    n = tgt.shape[0]
+    tgt, pod = lax.sort((tgt, lax.iota(jnp.int32, n) // (n // P_)), num_keys=1)
+    bounds = jnp.searchsorted(tgt, lax.iota(jnp.int32, T + 1))
+
+    record = jnp.concatenate(
+        [_limbs(pods.req), pods.req_present.astype(jnp.int32)], axis=1
+    )  # int32[P, 4R]
+    rows = -(-n // _LANES)
+    x = jnp.take(record, pod, axis=0, mode="clip").T  # [4R, n]
+    x = jnp.pad(x, ((0, 0), (0, rows * _LANES - n))).reshape(4 * R, rows, _LANES)
+    row = jnp.minimum(bounds // _LANES, rows - 1)  # b = n ends the last row
+    lane = lax.broadcasted_iota(jnp.int32, (1, 1, _LANES), 2)
+    head = (bounds - row * _LANES)[None, :, None]
+    part = jnp.take(x, row, axis=1, mode="clip")  # [4R, T+1, _LANES]
+    part = jnp.sum(jnp.where(lane < head, part, 0), axis=2, dtype=jnp.int32)
+    whole = jnp.sum(x, axis=2, dtype=jnp.int32)  # [4R, rows]
+
+    def segment_sums(whole, part):  # [C, rows], [C, T+1] → [C, T]
+        before = lax.cumsum(whole, axis=1) - whole
+        at = jnp.take(before, row, axis=1, mode="clip") + part
+        return at[:, 1:] - at[:, :-1]
+
+    used_cnt = (bounds[1:] - bounds[:-1]).astype(jnp.int64)
+    used_req = segment_sums(_unlimb(whole[:3 * R]), _unlimb(part[:3 * R])).T
+    contrib = segment_sums(whole[3 * R:], part[3 * R:]).T
+    return used_cnt, used_req, contrib
+
+
 @partial(jax.jit, static_argnames=("on_equal", "step3_on_equal", "pod_axis", "thr_axis"))
 def full_update_step_gather(
     sched: OverrideSchedule,
@@ -165,9 +230,11 @@ def full_update_step_gather(
     comm shape to the dense ``sharded_full_update`` — two single-hop ICI
     all-reduces — with O(P·K) tiles instead of O(P·T).
 
-    used-aggregation is an exact int64 scatter-add over the flat [P·K]
-    (col, contribution) pairs (padded/uncounted/out-of-tile slots route to
-    an out-of-range index and drop); classification is
+    used-aggregation is exact in int64: the flat [P·K] slots
+    (padded/uncounted/out-of-tile ones routed to the out-of-range target
+    T) are sorted by target once, and each throttle's count and sums are
+    differences of prefix sums at its segment's bounds
+    (``_used_by_target``); classification is
     ``check_pods_gather`` against the freshly derived state. Returns the
     same tuple as ``full_update_step``: (counts int32[P,4],
     schedulable bool[P], used_cnt int64[T], used_req int64[T,R],
@@ -175,8 +242,6 @@ def full_update_step_gather(
     from ..ops.check import check_pods_gather
 
     T = thr_valid.shape[0]
-    P_, K = cols.shape
-    R = pods.req.shape[1]
 
     if thr_axis is not None:
         # rebase global col ids into this shard's tile; foreign slots pad
@@ -190,26 +255,7 @@ def full_update_step_gather(
 
     slot = (cols >= 0) & (counted & pods.valid)[:, None]  # [P,K]
     tgt = jnp.where(slot, cols, T).reshape(-1)  # T = out of range ⇒ dropped
-    used_cnt = jnp.zeros(T, dtype=jnp.int64).at[tgt].add(1, mode="drop")
-    # R-LEADING scatter operands: the naive [P·K, R] update-row matrix
-    # tile-pads R=8 → 128 lanes on TPU — a 16× expansion (8.6G at the
-    # 131072-pod ladder cap), the OOM class the gather kernels once hit.
-    # With [R, P·K] rows scattering into an [R, T] accumulator the huge
-    # P·K count rides the un-padded lane dim and R the sublane dim;
-    # transposing back costs one [T,R].
-    req_rows = jnp.broadcast_to(pods.req.T[:, :, None], (R, P_, K)).reshape(R, P_ * K)
-    pres_rows = jnp.broadcast_to(
-        pods.req_present.T[:, :, None], (R, P_, K)
-    ).reshape(R, P_ * K)
-    used_req = (
-        jnp.zeros((R, T), dtype=jnp.int64).at[:, tgt].add(req_rows, mode="drop").T
-    )
-    contrib = (
-        jnp.zeros((R, T), dtype=jnp.int32)
-        .at[:, tgt]
-        .add(pres_rows.astype(jnp.int32), mode="drop")
-        .T
-    )
+    used_cnt, used_req, contrib = _used_by_target(pods, tgt, T)
     if pod_axis is not None:
         used_cnt = jax.lax.psum(used_cnt, pod_axis)
         used_req = jax.lax.psum(used_req, pod_axis)

@@ -128,32 +128,53 @@ def test_check_pods_compact_compiles(one_chip):
     _fits(compiled)
 
 
-@pytest.mark.parametrize("mesh_shape", [(2, 2), (4, 1)])
-def test_sharded_full_update_gather_compiles(topo, mesh_shape):
-    """The /v1/tick program over a ("pods","throttles") mesh of four
-    described chips, at the daemon's widths (K=32: 20 throttles a pod)."""
+def _tick_args(pods_sharding, thr_sharding, scalar_sharding):
+    """Shapes of the tick program's arguments at the daemon's widths (K=32:
+    20 throttles a pod)."""
     from kube_throttler_tpu.ops.overrides import encode_override_schedule
-    from kube_throttler_tpu.parallel.sharded import sharded_full_update_gather
 
-    mesh = Mesh(np.array(topo.devices[:4]).reshape(mesh_shape), ("pods", "throttles"))
-    on_pods = NamedSharding(mesh, P("pods"))
-    on_thr = NamedSharding(mesh, P("throttles"))
     state, pods = _small()
     R = state.thr_req.shape[1]
     sched = encode_override_schedule([None] * 8, DimRegistry(), throttle_capacity=8,
                                      override_capacity=1)
+    return (
+        _widen(sched, TCAP, thr_sharding),
+        _widen(pods, PCAP, pods_sharding),
+        _shape((PCAP, 32), jnp.int32, pods_sharding),
+        _shape((PCAP,), jnp.bool_, pods_sharding),
+        _shape((TCAP,), jnp.int64, thr_sharding),
+        _shape((TCAP,), jnp.bool_, thr_sharding),
+        _shape((TCAP, R), jnp.int64, thr_sharding),
+        _shape((TCAP, R), jnp.bool_, thr_sharding),
+        _shape((TCAP,), jnp.bool_, thr_sharding),
+        _shape((), jnp.int64, scalar_sharding),
+    )
+
+
+def test_full_update_step_gather_compiles(one_chip):
+    """The /v1/tick program on one chip at [131072, 32], T 16384, R 8: the
+    sort of its 4M slots by target and the prefix sums that rebuild
+    ``used``, then the gather."""
+    from kube_throttler_tpu.parallel.sharded import full_update_step_gather
+
+    compiled = full_update_step_gather.lower(
+        *_tick_args(one_chip, one_chip, one_chip), on_equal=False, step3_on_equal=True
+    ).compile()
+    _fits(compiled)
+    assert " sort(" in compiled.as_text()
+
+
+@pytest.mark.parametrize("mesh_shape", [(2, 2), (4, 1)])
+def test_sharded_full_update_gather_compiles(topo, mesh_shape):
+    """The /v1/tick program over a ("pods","throttles") mesh of four
+    described chips, at the daemon's widths (K=32: 20 throttles a pod)."""
+    from kube_throttler_tpu.parallel.sharded import sharded_full_update_gather
+
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(mesh_shape), ("pods", "throttles"))
     step = sharded_full_update_gather(mesh, on_equal=False, step3_on_equal=True)
     compiled = step.lower(
-        _widen(sched, TCAP, on_thr),
-        _widen(pods, PCAP, on_pods),
-        _shape((PCAP, 32), jnp.int32, on_pods),
-        _shape((PCAP,), jnp.bool_, on_pods),
-        _shape((TCAP,), jnp.int64, on_thr),
-        _shape((TCAP,), jnp.bool_, on_thr),
-        _shape((TCAP, R), jnp.int64, on_thr),
-        _shape((TCAP, R), jnp.bool_, on_thr),
-        _shape((TCAP,), jnp.bool_, on_thr),
-        _shape((), jnp.int64, NamedSharding(mesh, P())),
+        *_tick_args(NamedSharding(mesh, P("pods")), NamedSharding(mesh, P("throttles")),
+                    NamedSharding(mesh, P()))
     ).compile()
     _fits(compiled)
     assert "all-reduce" in compiled.as_text()

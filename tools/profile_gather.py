@@ -1,26 +1,31 @@
 """Time and profile the sparse [P,K] gather kernel (``check_pods_gather``)
-on one device, at the shapes the daemon dispatches at 100k pods x 10k
-throttles. Run from the root of a checkout (on a TPU, or on the CPU at a
-tiny size):
+or, with ``--tick``, the fused tick program (``full_update_step_gather``:
+thresholds, the ``used`` aggregation, the same gather) on one device, at
+the shapes the daemon dispatches at 100k pods x 10k throttles. Run from
+the root of a checkout (on a TPU, or on the CPU at a tiny size):
 
-    python tools/profile_gather.py [--pcap 131072] [--tcap 16384] [--R 8]
-        [--k 32 --k 64] [--peak-k 2048] [--out <trace dir>]
+    python tools/profile_gather.py [--tick] [--pcap 131072] [--tcap 16384]
+        [--R 8] [--k 32 --k 64] [--peak-k 2048] [--out <trace dir>]
 
 The state is synthetic, drawn from --seed in the shape of the
 k8s-large-100k configuration: throttles in groups of 20 with a pod-count
 and a cpu threshold, used and reserved near the thresholds, a few
 status flags; each pod matches its group's 20 throttles and one open
-one. Per K it prints one JSON line: the mean device time of a call
-(host clock around ``block_until_ready``), the time per [P,K] slot, and
-from a ``jax.profiler`` trace of a few calls the device time of each HLO
-op of the program, largest first, with whether the op gathers, and the
-share of all op time spent in ops that gather. ``--peak-k`` runs that
-rung last and reports the device's peak bytes in use, where the backend
-reports it. Traces go under ``--out`` (a new temporary directory by
-default).
+one. The tick takes the same thresholds as a schedule without overrides
+and counts every valid pod into ``used``. Per K it prints one JSON line:
+the mean device time of a call (host clock around
+``block_until_ready``), the time per [P,K] slot, and from a
+``jax.profiler`` trace of a few calls the device time of each HLO op of
+the program, largest first, with the kinds of op it holds (gather,
+scatter, sort, reduce-window), the share of all op time spent in ops
+of each kind, and a digest of the outputs, which two checkouts' runs
+must share. ``--peak-k`` runs that rung last and reports the device's
+peak bytes in use, where the backend reports it. Traces go under
+``--out`` (a new temporary directory by default).
 """
 
 import argparse
+import hashlib
 import json
 import os
 import re
@@ -81,6 +86,28 @@ def synthetic(seed: int, pcap: int, tcap: int, R: int, pods: int, throttles: int
     return state, batch, np.concatenate([matches, open_col], axis=1)
 
 
+def tick_inputs(state, batch):
+    """(the schedule, the arguments after cols) of the fused tick, from
+    the synthetic state: its thresholds as a schedule without overrides,
+    every valid pod counted, its reservations and validity, the epoch as
+    now."""
+    from kube_throttler_tpu.ops.overrides import OverrideSchedule
+
+    T, R = state.thr_req.shape
+    sched = OverrideSchedule(
+        ov_valid=np.zeros((T, 1), dtype=bool), ov_begin=np.zeros((T, 1), dtype=np.int64),
+        ov_end=np.zeros((T, 1), dtype=np.int64), ov_cnt=np.zeros((T, 1), dtype=np.int64),
+        ov_cnt_present=np.zeros((T, 1), dtype=bool),
+        ov_req=np.zeros((T, 1, R), dtype=np.int64),
+        ov_req_present=np.zeros((T, 1, R), dtype=bool), spec_cnt=state.thr_cnt,
+        spec_cnt_present=state.thr_cnt_present, spec_req=state.thr_req,
+        spec_req_present=state.thr_req_present,
+    )
+    rest = (batch.valid.copy(), state.res_cnt, state.res_cnt_present, state.res_req,
+            state.res_req_present, state.valid, np.int64(0))
+    return sched, rest
+
+
 def cols_for(matches: np.ndarray, pcap: int, k: int) -> np.ndarray:
     cols = np.full((pcap, k), -1, dtype=np.int32)
     w = min(k, matches.shape[1])
@@ -88,30 +115,38 @@ def cols_for(matches: np.ndarray, pcap: int, k: int) -> np.ndarray:
     return cols
 
 
-def _gather_computations(hlo: str) -> set:
-    """Names of the computations in an optimized HLO module's text that
-    hold a gather."""
-    names, current = set(), None
+KINDS = ("gather", "scatter", "sort", "reduce-window")
+
+
+def _kinds_of(text: str) -> set:
+    return {k for k in KINDS if f" {k}(" in text}
+
+
+def _computation_kinds(hlo: str) -> dict:
+    """Computation name → the KINDS of op it holds, from an optimized HLO
+    module's text."""
+    kinds, current = {}, None
     for line in hlo.splitlines():
         m = re.match(r"^(?:ENTRY )?(%\S+) ", line)
         if m:
             current = m.group(1)
-        elif " gather(" in line and current:
-            names.add(current)
-    return names
+        elif current:
+            kinds.setdefault(current, set()).update(_kinds_of(line))
+    return kinds
 
 
 def op_profile(trace_dir: str, hlo: str, top: int):
-    """(share of device op time in ops that gather, [[op, shape, seconds,
-    share, gathers]]) from the trace. Control-flow ops (a while around the
-    blocks) span their body's ops and are left out of the total."""
+    """({kind: share of device op time in ops of that kind}, [[op, shape,
+    seconds, share, kinds]]) from the trace. Control-flow ops (a while
+    around the blocks) span their body's ops and are left out of the
+    total."""
     from benchmark.devtrace import find_xplane
     from jax.profiler import ProfileData
 
     path = find_xplane(trace_dir)
     if path is None:
         return None, []
-    gathering = _gather_computations(hlo)
+    computations = _computation_kinds(hlo)
     sums, info = {}, {}
     for plane in ProfileData.from_file(path).planes:
         if not plane.name.startswith("/device:"):
@@ -124,19 +159,20 @@ def op_profile(trace_dir: str, hlo: str, top: int):
                 if m is None or re.search(r" (while|conditional|call)\(", e.name):
                     continue
                 calls = re.search(r"calls=(%[^,\s]+)", e.name)
+                kinds = _kinds_of(e.name) | computations.get(calls.group(1) if calls else "", set())
                 sums[m.group(1)] = sums.get(m.group(1), 0) + e.duration_ns
-                info[m.group(1)] = (m.group(2).split("{")[0], bool(
-                    " gather(" in e.name or (calls and calls.group(1) in gathering)))
+                info[m.group(1)] = (m.group(2).split("{")[0], sorted(kinds))
     if not sums:
         return None, []
     total = sum(sums.values())
-    share = sum(ns for op, ns in sums.items() if info[op][1]) / total
+    shares = {k: sum(ns for op, ns in sums.items() if k in info[op][1]) / total for k in KINDS}
     ranked = sorted(sums.items(), key=lambda kv: -kv[1])[:top]
-    return share, [[op, info[op][0], ns / 1e9, ns / total, info[op][1]] for op, ns in ranked]
+    return shares, [[op, info[op][0], ns / 1e9, ns / total, info[op][1]] for op, ns in ranked]
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--tick", action="store_true")
     ap.add_argument("--pcap", type=int, default=131072)
     ap.add_argument("--tcap", type=int, default=16384)
     ap.add_argument("--R", type=int, default=8)
@@ -155,40 +191,51 @@ def main(argv=None) -> int:
 
     import kube_throttler_tpu  # noqa: F401  (x64 on before any array)
     from kube_throttler_tpu.ops.check import check_pods_gather
+    from kube_throttler_tpu.parallel.sharded import full_update_step_gather
 
     dev = jax.devices()[0]
     state, batch, matches = synthetic(
         args.seed, args.pcap, args.tcap, args.R, args.pods, args.throttles)
-    state, batch = jax.device_put((state, batch))
+    if args.tick:
+        fn = full_update_step_gather
+        sched, rest = jax.device_put(tick_inputs(state, batch))
+        batch = jax.device_put(batch)
+        args_for = lambda cols: (sched, batch, cols) + rest  # noqa: E731
+    else:
+        fn = check_pods_gather
+        state, batch = jax.device_put((state, batch))
+        args_for = lambda cols: (state, batch, cols)  # noqa: E731
     ks = args.k or [32]
-    for k in ks + ([args.peak_k] if args.peak_k else []):
+    for k, peak in [(k, False) for k in ks] + ([(args.peak_k, True)] if args.peak_k else []):
         cols = jax.device_put(cols_for(matches, args.pcap, k))
         t0 = time.perf_counter()
-        jax.block_until_ready(check_pods_gather(state, batch, cols))
+        jax.block_until_ready(fn(*args_for(cols)))
         compile_s = time.perf_counter() - t0
-        calls = 1 if k == args.peak_k else args.calls
+        calls = 1 if peak else args.calls
         t0 = time.perf_counter()
         for _ in range(calls):
-            out = check_pods_gather(state, batch, cols)
+            out = fn(*args_for(cols))
         jax.block_until_ready(out)
         call_s = (time.perf_counter() - t0) / calls
-        rec = {"k": k, "pcap": args.pcap, "tcap": args.tcap, "R": args.R,
-               "compile_s": compile_s, "call_ms": call_s * 1e3,
+        out = [np.asarray(o) for o in out]
+        rec = {"program": fn.__name__, "k": k, "pcap": args.pcap, "tcap": args.tcap,
+               "R": args.R, "compile_s": compile_s, "call_ms": call_s * 1e3,
                "ns_per_slot": call_s * 1e9 / (args.pcap * k),
-               "schedulable": int(np.asarray(out[1]).sum()),
-               "counts": np.asarray(out[0]).sum(axis=0).tolist(),
+               "schedulable": int(out[1].sum()),
+               "counts": out[0].sum(axis=0).tolist(),
+               "outputs_sha256": hashlib.sha256(b"".join(o.tobytes() for o in out)).hexdigest(),
                "device": {"platform": dev.platform, "kind": dev.device_kind}}
-        if k == args.peak_k:
+        if peak:
             rec["peak_bytes_in_use"] = (dev.memory_stats() or {}).get("peak_bytes_in_use")
         else:
             trace_dir = os.path.join(out_dir, f"k{k}")
             with jax.profiler.trace(trace_dir):
                 for _ in range(3):
-                    out = check_pods_gather(state, batch, cols)
+                    out = fn(*args_for(cols))
                 jax.block_until_ready(out)
-            hlo = check_pods_gather.lower(state, batch, cols).compile().as_text()
+            hlo = fn.lower(*args_for(cols)).compile().as_text()
             rec["trace"] = trace_dir
-            rec["gather_share"], rec["ops"] = op_profile(trace_dir, hlo, args.top)
+            rec["shares"], rec["ops"] = op_profile(trace_dir, hlo, args.top)
         print(json.dumps(rec), flush=True)
     return 0
 
